@@ -30,6 +30,7 @@ import time
 from repro.core import DLConfig
 
 from benchmarks.common import save_results
+from repro.utils.compile_cache import enable_compile_cache
 
 WL = {"dataset": "cifar10", "model": "mlp", "width": 1,
       "n_train": 256, "n_test": 128, "lr": 0.05}
@@ -132,4 +133,5 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -42,6 +42,7 @@ from repro.data import NodeBatcher, make_dataset, sharding_partition
 from repro.optim import make_optimizer
 
 from benchmarks.common import dl_experiment, save_results
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def run(nodes: int = 32, rounds: int = 40, model: str = "mlp", seeds: int = 1,
@@ -173,4 +174,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -14,12 +14,13 @@ while the dense path stages (R, N, N) W stacks that hit the 64 MB cap and
 silently shrink the chunk exactly where scale matters.  Gate: sparse ≥ 3x
 dense rounds/s at N=1024.
 
-Part 3 measures the node-sharded engine (shard_devices=8, both the
-'gather' and the collective_permute 'ppermute' gossip lowerings) against
-the single-device engine at N=1024, d=6 on 8 CPU-emulated devices — the
-honest emulation cost of multi-device execution on one box (emulated
-collectives are host rendezvous; the wire win is a TPU story).  Runs in a
-subprocess with XLA_FLAGS set when the current process has fewer devices.
+Part 3 measures the node-sharded engine (shard_devices=4 by default, a
+v5e host's chips; both the 'gather' and the collective_permute 'ppermute'
+gossip lowerings) against the single-device engine at N=1024, d=6.  On
+CPU-emulated devices that is the emulation cost of multi-device execution
+on one box (emulated collectives are host rendezvous; the wire win is a
+TPU story); on the CPU backend it runs in a subprocess with XLA_FLAGS set
+when the current process has fewer devices.
 
 Part 4 measures payload-form compressed sharing (DLConfig.payload='on':
 (N, k) idx/val payloads aggregated in one O(N·d·k) scatter pass) against
@@ -64,6 +65,7 @@ from repro.data import NodeBatcher, make_dataset, sharding_partition
 from repro.optim import make_optimizer
 
 from benchmarks.common import save_results
+from repro.utils.compile_cache import enable_compile_cache
 
 SHAPE = (2, 2, 1)  # 4-dim inputs; batch staging stays negligible
 P_DISPATCH = 4     # part 1: 4-param state isolates the dispatch machinery
@@ -426,7 +428,7 @@ def run_async(rounds: int = 96, n: int = 1024, degree: int = 6, chunk: int = 32,
 
 
 def run_sharded(rounds: int = 12, n: int = 1024, degree: int = 6, chunk: int = 32,
-                repeats: int = 3, devices: int = 8, log: bool = True):
+                repeats: int = 3, devices: int = 4, log: bool = True):
     """Part 3: node-sharded vs single-device RoundEngine at the paper's
     1000+-node scale (N=1024, d=6, chunk=32, static d-regular overlay).
 
@@ -440,15 +442,23 @@ def run_sharded(rounds: int = 12, n: int = 1024, degree: int = 6, chunk: int = 3
     story).  The single-device baseline runs in the *same* process so both
     see the same host contention.
 
-    When the current process doesn't have enough devices the section
-    re-executes itself in a subprocess with the XLA flag set (device count
-    locks at first jax init), so a plain ``python benchmarks/bench_engine.py``
-    still records the sharded entries.
+    On the CPU backend, when the current process doesn't have enough
+    devices, the section re-executes itself in a subprocess with the XLA
+    flag set (device count locks at first jax init), so a plain
+    ``python benchmarks/bench_engine.py`` still records the sharded
+    entries.  On an accelerator this process already holds the devices, so
+    a child could not use them: it fails instead.
     """
     recs = []
     if rounds <= 0:
         return recs
     if jax.device_count() < devices:
+        if jax.default_backend() != "cpu":
+            raise RuntimeError(
+                f"--sharded-devices {devices} needs that many devices; "
+                f"{jax.device_count()} {jax.default_backend()} devices are "
+                "visible (pass --sharded-devices <= the visible count)"
+            )
         return _run_sharded_subprocess(rounds, n, degree, chunk, repeats, devices, log)
     cases = {
         "single": dict(),
@@ -540,7 +550,8 @@ def main():
                     help="rounds for the N=1024 sharded-vs-single section; 0 skips it")
     ap.add_argument("--sharded-degree", type=int, default=6)
     ap.add_argument("--sharded-repeats", type=int, default=3)
-    ap.add_argument("--sharded-devices", type=int, default=8)
+    ap.add_argument("--sharded-devices", type=int, default=4,
+                    help="node-axis mesh size (a v5e host has 4 chips)")
     ap.add_argument("--sharded-chunk", type=int, default=32)
     ap.add_argument("--_sharded-worker", action="store_true",
                     help=argparse.SUPPRESS)
@@ -600,4 +611,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -35,6 +35,7 @@ from repro.data import NodeBatcher, make_dataset, sharding_partition
 from repro.optim import make_optimizer
 
 from benchmarks.common import save_results
+from repro.utils.compile_cache import enable_compile_cache
 
 MSG_LOSS = 0.10
 GATE_MAX_SLOWDOWN = 1.5
@@ -145,4 +146,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ref
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def _time(fn, *args, reps=5):
@@ -78,4 +79,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -49,6 +49,7 @@ from repro.core import DLConfig, RoundEngine
 from repro.core.topology import random_regular_neighbors
 from repro.data import NodeBatcher
 from repro.optim import make_optimizer
+from repro.utils.compile_cache import enable_compile_cache
 
 SHAPE = (4, 4, 1)
 N_CLASSES = 2
@@ -490,4 +491,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

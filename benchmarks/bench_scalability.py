@@ -11,6 +11,7 @@ import argparse
 from repro.core import DLConfig
 
 from benchmarks.common import dl_experiment, save_results
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def run(base_nodes: int = 256, rounds: int = 60, model: str = "mlp", seeds: int = 1,
@@ -44,4 +45,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

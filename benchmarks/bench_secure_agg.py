@@ -11,6 +11,7 @@ import argparse
 from repro.core import DLConfig
 
 from benchmarks.common import dl_experiment, save_results
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def run(nodes: int = 16, rounds: int = 80, model: str = "mlp", seeds: int = 1,
@@ -43,4 +44,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -10,6 +10,7 @@ import argparse
 from repro.core import DLConfig
 
 from benchmarks.common import dl_experiment, save_results
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def run(nodes: int = 32, rounds: int = 120, model: str = "mlp", seeds: int = 1,
@@ -67,4 +68,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -13,6 +13,8 @@ import argparse
 import sys
 import time
 
+from repro.utils.compile_cache import enable_compile_cache
+
 
 def _line(name, us, derived):
     print(f"{name},{us:.0f},{derived}", flush=True)
@@ -128,4 +130,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -23,6 +23,7 @@ import argparse
 
 from repro.core import DLConfig
 from repro.runtime import ProcessRunner
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
@@ -111,4 +112,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 from repro.configs import ARCHS, get_smoke_config
 from repro.models.api import init_params
 from repro.serving import ServeConfig, ServingEngine
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
@@ -37,4 +38,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -12,6 +12,7 @@ from repro.data import NodeBatcher, make_dataset, sharding_partition
 from repro.models.api import cross_entropy
 from repro.models.mlp import mlp_apply, mlp_init
 from repro.optim import make_optimizer
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
@@ -38,4 +39,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

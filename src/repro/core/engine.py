@@ -95,6 +95,46 @@ _W_STACK_BYTES_CAP = 64 * 1024 * 1024
 # either way (property-tested), so the threshold only moves memory.
 _DENSE_GRAPH_MAX_N = 4096
 
+# bytes of the largest per-node intermediate, times the nodes evaluated
+# together, that one evaluation step may hold (RoundEngine.over_nodes).
+# A vmap over all N nodes holds N copies of each activation of the test
+# batch: 16 GiB for GN-LeNet's first conv at N=256 and 512 images, which
+# one 16 GB chip refuses.
+EVAL_BYTES = 1 << 30
+
+
+def _largest_intermediate(jaxpr) -> int:
+    """Bytes of the largest value any equation of ``jaxpr`` (and of the
+    jaxprs nested in its equations) produces."""
+    big = 0
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            aval = v.aval
+            if hasattr(aval, "shape"):
+                big = max(big, int(np.prod(aval.shape)) * aval.dtype.itemsize)
+        for sub in eqn.params.values():
+            for j in sub if isinstance(sub, (tuple, list)) else (sub,):
+                j = getattr(j, "jaxpr", j)
+                if hasattr(j, "eqns"):
+                    big = max(big, _largest_intermediate(j))
+    return big
+
+
+def over_nodes(fn, params, *args):
+    """``jax.vmap(fn)`` over the stacked node axis of ``params``, with
+    ``args`` shared, in groups small enough that the largest per-node
+    intermediate times the group stays within ``EVAL_BYTES``: one vmap
+    when all N fit (every small model), sequential groups otherwise."""
+    n = jax.tree_util.tree_leaves(params)[0].shape[0]
+    one = jax.tree_util.tree_map(lambda a: a[0], params)
+    per_node = _largest_intermediate(
+        jax.make_jaxpr(lambda p: fn(p, *args))(one).jaxpr)
+    group = max(1, EVAL_BYTES // max(per_node, 1))
+    f = lambda p: fn(p, *args)
+    if group >= n:
+        return jax.vmap(f)(params)
+    return jax.lax.map(f, params, batch_size=group)
+
 
 @dataclasses.dataclass
 class DLConfig:
@@ -743,7 +783,7 @@ class RoundEngine:
         return self.scheduler.participation_mask(start, n_rounds)
 
     def _eval(self, params, tx, ty):
-        return jax.vmap(lambda p: self.acc_fn(p, tx, ty))(params)
+        return over_nodes(self.acc_fn, params, tx, ty)
 
     # ------------------------------------------------------------------
     def _record(self, rnd: int, tx, ty, t0: float, log: bool):

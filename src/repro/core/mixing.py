@@ -10,9 +10,9 @@ x_i' = sum_j W_ij x_j  (W = Metropolis-Hastings weights of the overlay):
 * ``mix_sparse``     — neighbor-indexed gather + weighted segment sum over
                        a ``SparseTopology``'s padded (N, D) tables:
                        O(N·D·P) FLOPs, the execution form for sparse graphs
-                       (d ≪ N).  Optionally routes the fused K-way merge
-                       through the ``kernels/gossip_mix`` Pallas kernel
-                       (compiled on TPU, interpret elsewhere).  This is
+                       (d ≪ N).  On TPU the fused K-way merge runs in
+                       the ``kernels/gossip_mix`` Pallas kernel (XLA
+                       gather + einsum on other backends).  This is
                        also the neighbor-indexed form multi-host
                        `collective_permute` gossip shards over.
 * ``mix_circulant``  — static circulant d-regular graphs; neighbor exchange
@@ -47,6 +47,7 @@ from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core.topology import (
@@ -57,8 +58,11 @@ from repro.core.topology import (
     decompose_slot_permutations,
     sample_neighbor_slots,
 )
-from repro.utils.compat import shard_map
 
+# Mixing products in fp32 on every backend.  TPU's default precision for an
+# fp32 contraction rounds its operands to bf16, and bf16 Metropolis-Hastings
+# weights no longer sum to 1, so the consensus average would drift.
+F32 = jax.lax.Precision.HIGHEST
 
 # ---------------------------------------------------------------------------
 # node-sharded gossip: the distributed backends of mix_sparse / apply_W
@@ -211,7 +215,7 @@ class ShardedTopology:
         )
         if self.sched is None:
             g = jnp.take(self.shard.gather(Yf), self.topo.nbr, axis=0)
-            return w_self * Yf + jnp.einsum("nd,nd...->n...", w, g)
+            return w_self * Yf + jnp.einsum("nd,nd...->n...", w, g, precision=F32)
         acc = w_self * Yf
         for s, slot_sched in enumerate(self.sched.slots):
             xs = _permute_block(Yf, slot_sched, self.shard)
@@ -233,7 +237,8 @@ class ShardedDense:
 
     def apply(self, Yf):
         return jnp.einsum(
-            "bn,n...->b...", self.W.astype(jnp.float32), self.shard.gather(Yf)
+            "bn,n...->b...", self.W.astype(jnp.float32), self.shard.gather(Yf),
+            precision=F32,
         )
 
 
@@ -254,7 +259,8 @@ def mix_dense(stacked, W):
     W = W.astype(jnp.float32)
 
     def f(a):
-        return jnp.einsum("ij,j...->i...", W, a.astype(jnp.float32)).astype(a.dtype)
+        return jnp.einsum("ij,j...->i...", W, a.astype(jnp.float32),
+                          precision=F32).astype(a.dtype)
 
     return jax.tree_util.tree_map(f, stacked)
 
@@ -271,49 +277,60 @@ def apply_W(W, Y):
     if isinstance(W, (ShardedTopology, ShardedDense)):
         return W.apply(Yf)  # inside a shard_map body: Y is this device's rows
     if isinstance(W, SparseTopology):
-        g = jnp.take(Yf, W.nbr, axis=0)  # (N, D, ...)
-        mixed = jnp.einsum("nd,nd...->n...", W.w.astype(jnp.float32), g)
-        w_self = W.w_self.astype(jnp.float32).reshape(
-            (Yf.shape[0],) + (1,) * (Yf.ndim - 1)
-        )
-        return w_self * Yf + mixed
-    return jnp.einsum("ij,j...->i...", W.astype(jnp.float32), Yf)
+        return _mix_rows(W, Yf)
+    return jnp.einsum("ij,j...->i...", W.astype(jnp.float32), Yf, precision=F32)
 
 
-def mix_sparse(stacked, topo: SparseTopology, *, use_pallas: Optional[bool] = None,
-               interpret: Optional[bool] = None):
-    """Neighbor-indexed gossip over a pytree: x_i' = w_self_i x_i +
-    sum_k w[i,k] x_nbr[i,k] per leaf — O(N·D·P).
+def _mix_rows(topo: SparseTopology, Yf):
+    """w_self_i Y_i + sum_k w[i,k] Y_nbr[i,k] for fp32 Yf (N, ...).
 
-    use_pallas: route the fused (D+1)-way weighted merge through the
+    On TPU the fused (D+1)-way weighted merge runs in the
     ``kernels.gossip_mix`` Pallas kernel (one HBM pass per operand);
-    default: compiled kernel on TPU, plain XLA gather+einsum elsewhere.
-    interpret: force Pallas interpret mode (CPU emulation of the TPU
-    program); defaults to interpret off-TPU.
+    other backends run the XLA gather + einsum.
     """
-    if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
+    if jax.default_backend() != "tpu":
+        return gather_mix(topo, Yf)
+    from repro.kernels.ops import gossip_mix_nodes
 
-    def f(a):
-        af = a.astype(jnp.float32)
-        if not use_pallas:
-            return apply_W(topo, af).astype(a.dtype)
-        from repro.kernels.gossip_mix import gossip_mix_nodes
+    xs, ws = gossip_operands(topo, Yf.reshape(Yf.shape[0], -1))
+    return gossip_mix_nodes(xs, ws).reshape(Yf.shape)
 
-        n = af.shape[0]
-        flat = af.reshape(n, -1)
-        xs = jnp.concatenate(
-            [flat[:, None, :], jnp.take(flat, topo.nbr, axis=0)], axis=1
-        )  # (N, 1 + D, P)
-        ws = jnp.concatenate(
-            [topo.w_self.astype(jnp.float32)[:, None], topo.w.astype(jnp.float32)],
-            axis=1,
-        )
-        it = (jax.default_backend() != "tpu") if interpret is None else interpret
-        out = gossip_mix_nodes(xs, ws, interpret=it)
-        return out.reshape(af.shape).astype(a.dtype)
 
-    return jax.tree_util.tree_map(f, stacked)
+def gather_mix(topo: SparseTopology, Yf):
+    """The XLA form of :func:`_mix_rows`: gather (N, D, ...) neighbor rows,
+    contract the slot axis."""
+    g = jnp.take(Yf, topo.nbr, axis=0)
+    mixed = jnp.einsum("nd,nd...->n...", topo.w.astype(jnp.float32), g,
+                       precision=F32)
+    w_self = topo.w_self.astype(jnp.float32).reshape(
+        (Yf.shape[0],) + (1,) * (Yf.ndim - 1)
+    )
+    return w_self * Yf + mixed
+
+
+def gossip_operands(topo: SparseTopology, flat):
+    """The fused kernel's operands for rows ``flat`` (N, P): the slot-major
+    stack (1 + D, N, P) — self, then one gather per neighbor slot — and the
+    weights (N, 1 + D).  A single (D, N) gather is assembled by XLA on TPU
+    from split pieces with two copies live: 10.1 vs 5.3 GB of temp at
+    N=256, GN-LeNet."""
+    xs = jnp.stack([flat] + [jnp.take(flat, topo.nbr[:, d], axis=0)
+                             for d in range(topo.nbr.shape[1])])
+    ws = jnp.concatenate(
+        [topo.w_self.astype(jnp.float32)[:, None], topo.w.astype(jnp.float32)],
+        axis=1,
+    )
+    return xs, ws
+
+
+def mix_sparse(stacked, topo: SparseTopology):
+    """Neighbor-indexed gossip over a pytree: x_i' = w_self_i x_i +
+    sum_k w[i,k] x_nbr[i,k] per leaf — O(N·D·P); the ``apply_W`` sparse
+    form leaf by leaf.
+    """
+    return jax.tree_util.tree_map(
+        lambda a: apply_W(topo, a).astype(a.dtype), stacked
+    )
 
 
 # ---------------------------------------------------------------------------
@@ -378,9 +395,7 @@ def _payload_scatter(Xf, idx_ops, val_ops, w_ops):
     return Xf + delta
 
 
-def mix_payload(W, idx, val, X, *, exact_values: bool = True,
-                use_pallas: Optional[bool] = None,
-                interpret: Optional[bool] = None):
+def mix_payload(W, idx, val, X, *, exact_values: bool = True):
     """Payload-indexed sparse aggregation: X' from per-node payloads.
 
     W: dense (N, N), ``SparseTopology``, or the sharded wrappers
@@ -394,10 +409,11 @@ def mix_payload(W, idx, val, X, *, exact_values: bool = True,
     exactly zero and is skipped; pass False for quantized payloads so the
     dense rule's self-roundtrip term is reproduced.
 
-    Sparse/sharded forms run the gather + scatter-accumulate pass
-    (optionally through the fused ``kernels.scatter_gossip`` Pallas kernel:
-    compiled on TPU, XLA scatter elsewhere); a dense (N, N) W — the
-    all-pairs oracle regime — falls back to :func:`mix_payload_masked`.
+    Sparse/sharded forms run the XLA gather + scatter-accumulate pass on
+    every backend.  The ``kernels.scatter_gossip`` Pallas form is not used:
+    its in-VMEM one-hot scatter is (K·k, BN) per block, about 9 GB at
+    GN-LeNet width and budget 0.01.  A dense (N, N) W — the all-pairs
+    oracle regime — falls back to :func:`mix_payload_masked`.
     """
     Xf = X.astype(jnp.float32)
     valf = val.astype(jnp.float32)
@@ -410,15 +426,6 @@ def mix_payload(W, idx, val, X, *, exact_values: bool = True,
         idx_ops, val_ops, w_ops = _payload_operands(
             W, idx, valf, include_self=not exact_values
         )
-        if use_pallas is None:
-            use_pallas = jax.default_backend() == "tpu"
-        if use_pallas:
-            from repro.kernels.scatter_gossip import payload_mix_nodes
-
-            it = (jax.default_backend() != "tpu") if interpret is None else interpret
-            return payload_mix_nodes(
-                Xf, idx_ops, val_ops, w_ops, interpret=it
-            ).astype(jnp.float32)
         return _payload_scatter(Xf, idx_ops, val_ops, w_ops)
     return mix_payload_masked(W, idx, valf, Xf)
 

@@ -45,11 +45,12 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import compression as compression_lib
 from repro.core import faults as faults_lib
-from repro.core.mixing import ShardedDense, ShardedTopology, gossip_pair_avg
+from repro.core.mixing import F32, ShardedDense, ShardedTopology, gossip_pair_avg
 from repro.data.loader import node_batch_indices
 from repro.core.sharing import (
     edge_reweight,
@@ -61,7 +62,6 @@ from repro.core.sharing import (
 )
 from repro.core.steps import node_where
 from repro.core.topology import SparseTopology, gather_rows, sample_neighbor_slots
-from repro.utils.compat import shard_map
 from repro.utils.pytree import tree_unvector, tree_vector
 
 # cap on the pre-gathered (R, L, N, B, ...) batch stack; above it the scan
@@ -1093,7 +1093,8 @@ class AsyncScheduler(Scheduler):
             Xn = fresh_rows(nbr_flat, jax.vmap(tree_vector)(p_n)).reshape(
                 X_c.shape[0], -1, X_c.shape[1]
             )                                                  # (C, D, P)
-            mixed = jnp.einsum("cd,cdp->cp", Wm_c.w.astype(jnp.float32), Xn)
+            mixed = jnp.einsum("cd,cdp->cp", Wm_c.w.astype(jnp.float32), Xn,
+                               precision=F32)
             X2_all = Wm_c.w_self.astype(jnp.float32)[:, None] * X_c + mixed
             X2_c = jnp.where(actv_c[:, None] > 0, X2_all, X_c)
             live_c = topo_c.w > 0

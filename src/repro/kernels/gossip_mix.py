@@ -47,39 +47,49 @@ def gossip_mix(neighbors, weights, *, interpret: bool = False, block_n: int = BL
     return out[:M]
 
 
+ROWS = 8          # receivers per block: the second-minor block dim must be a
+#                  multiple of 8 (or the whole axis) to compile for TPU
+BLOCK_N_NODES = 8192  # (K, ROWS, BN) fp32 block: 1.5 MiB at K = 6, so the
+#                  double-buffered operands sit well inside scoped VMEM
+
+
 def _kernel_nodes(w_ref, x_ref, o_ref):
-    # x_ref: (1, K, BN); w_ref: (1, K, 1); o_ref: (1, BN)
-    x = x_ref[...].astype(jnp.float32)
+    # x_ref: (K, R, BN) slot-major operands; w_ref: (R, K); o_ref: (R, BN)
     w = w_ref[...].astype(jnp.float32)
-    o_ref[...] = jnp.sum(x * w, axis=1).astype(o_ref.dtype)
+    acc = None
+    for s in range(x_ref.shape[0]):
+        term = x_ref[s].astype(jnp.float32) * w[:, s:s + 1]
+        acc = term if acc is None else acc + term
+    o_ref[...] = acc.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_n"))
-def gossip_mix_nodes(neighbors, weights, *, interpret: bool = False,
-                     block_n: int = BLOCK_N):
-    """Node-batched fused gossip merge — the ``mix_sparse`` backend.
+def gossip_mix_nodes(slots, weights, *, interpret: bool = False,
+                     block_n: int = BLOCK_N_NODES):
+    """Node-batched fused gossip merge — ``apply_W``'s sparse form on TPU.
 
-    neighbors: (N, K, M) — for each of N receivers, its K = 1 + degree
-    gathered operand rows (self first); weights: (N, K) -> (N, M).
-    Grid (N, M/BN): each program fuses one receiver's K-way weighted sum
-    over one parameter block, reading every operand once from HBM.  The
-    param block adapts down to the (128-aligned) vector length so small
-    models don't pad to the full 64k block.
+    slots: (K, N, M) — slot s holds, for each of N receivers, its s-th
+    operand row (slot 0 = self, then the D = K - 1 neighbors); weights:
+    (N, K) -> (N, M), out[n] = sum_s weights[n, s] * slots[s, n].
+    Slot-major so the tiled (N, M) dims carry no padding (a node-major
+    (N, K, M) stack pads K up to 8 sublanes on TPU).  Grid (N/ROWS, M/BN):
+    each program fuses ROWS receivers' K-way weighted sums over one
+    parameter block, reading every operand once from HBM.  A dimension
+    shorter than its block is taken whole.  Ragged edges are partial
+    blocks, not padded copies: the op is columnwise, so the out-of-range
+    lanes and rows of an edge block only feed outputs that are dropped.
     """
-    N, K, M = neighbors.shape
-    bn = min(block_n, -(-M // 128) * 128)
-    pad = (-M) % bn
-    x = jnp.pad(neighbors, ((0, 0), (0, 0), (0, pad)))
-    grid = (N, x.shape[2] // bn)
-    out = pl.pallas_call(
+    K, N, M = slots.shape
+    bn, rows = min(block_n, M), min(ROWS, N)
+    return pl.pallas_call(
         _kernel_nodes,
-        grid=grid,
+        grid=(pl.cdiv(N, rows), pl.cdiv(M, bn)),
         in_specs=[
-            pl.BlockSpec((1, K, 1), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, K, bn), lambda b, i: (b, 0, i)),
+            pl.BlockSpec((rows, K), lambda b, i: (b, 0)),
+            pl.BlockSpec((K, rows, bn), lambda b, i: (0, b, i)),
         ],
-        out_specs=pl.BlockSpec((1, bn), lambda b, i: (b, i)),
-        out_shape=jax.ShapeDtypeStruct((N, x.shape[2]), neighbors.dtype),
+        out_specs=pl.BlockSpec((rows, bn), lambda b, i: (b, i)),
+        out_shape=jax.ShapeDtypeStruct((N, M), slots.dtype),
         interpret=interpret,
-    )(weights[:, :, None], x)
-    return out[:, :M]
+        name="gossip_mix_nodes",
+    )(weights, slots)

@@ -1,13 +1,11 @@
 """Jitted public wrappers around the Pallas kernels.
 
-On this CPU container every wrapper defaults to ``interpret=True`` (the
-kernel body executes in Python via the Pallas interpreter — bit-faithful to
-the TPU program).  On a real TPU, pass ``interpret=False`` (or set
-REPRO_PALLAS_COMPILE=1) to run the compiled kernels.
+Every wrapper takes ``interpret=None``, which means: compiled on the TPU
+backend, and run by the Pallas interpreter (bit-faithful to the TPU
+program, for CPU tests) on any other backend.  A caller may force either
+mode by passing a bool.
 """
 from __future__ import annotations
-
-import os
 
 import jax
 import jax.numpy as jnp
@@ -31,74 +29,76 @@ from repro.kernels.sparsify import topk_threshold_rows as _topk_threshold_rows
 from repro.kernels.ssd_chunk import ssd_chunk as _ssd_chunk
 from repro.kernels.swa_attention import swa_attention as _swa_attention
 
-INTERPRET = os.environ.get("REPRO_PALLAS_COMPILE", "0") != "1"
+
+def _interpret(interpret):
+    return jax.default_backend() != "tpu" if interpret is None else interpret
 
 
 def gossip_mix(neighbors, weights, interpret: bool = None):
     return _gossip_mix(neighbors, weights,
-                       interpret=INTERPRET if interpret is None else interpret)
+                       interpret=_interpret(interpret))
 
 
 def quantize(x, noise=None, interpret: bool = None):
-    return _quantize(x, noise, interpret=INTERPRET if interpret is None else interpret)
+    return _quantize(x, noise, interpret=_interpret(interpret))
 
 
 def dequantize(codes, scale, interpret: bool = None):
     return _dequantize(codes, scale,
-                       interpret=INTERPRET if interpret is None else interpret)
+                       interpret=_interpret(interpret))
 
 
 def secure_mask_apply(x, bits, signs, bound: float = 1.0, interpret: bool = None):
     return _secure_mask_apply(x, bits, signs, bound,
-                              interpret=INTERPRET if interpret is None else interpret)
+                              interpret=_interpret(interpret))
 
 
-def gossip_mix_nodes(neighbors, weights, interpret: bool = None):
-    return _gossip_mix_nodes(neighbors, weights,
-                             interpret=INTERPRET if interpret is None else interpret)
+def gossip_mix_nodes(slots, weights, interpret: bool = None):
+    return _gossip_mix_nodes(slots, weights,
+                             interpret=_interpret(interpret))
 
 
 def secure_mask_apply_nodes(x, bits, signs, bound: float = 1.0, interpret: bool = None):
     return _secure_mask_apply_nodes(x, bits, signs, bound,
-                                    interpret=INTERPRET if interpret is None else interpret)
+                                    interpret=_interpret(interpret))
 
 
 def secure_mask_apply_nodes_keyed(x, keys, signs, bound: float = 1.0,
                                   interpret: bool = None):
     return _secure_mask_apply_nodes_keyed(
         x, keys, signs, bound,
-        interpret=INTERPRET if interpret is None else interpret)
+        interpret=_interpret(interpret))
 
 
 def payload_mix_nodes(x, idx, val, w, interpret: bool = None):
     return _payload_mix_nodes(x, idx, val, w,
-                              interpret=INTERPRET if interpret is None else interpret)
+                              interpret=_interpret(interpret))
 
 
 def abs_histogram(x, edges, interpret: bool = None):
     return _abs_histogram(x, edges,
-                          interpret=INTERPRET if interpret is None else interpret)
+                          interpret=_interpret(interpret))
 
 
 def abs_histogram_rows(x, edges, interpret: bool = None):
     return _abs_histogram_rows(x, edges,
-                               interpret=INTERPRET if interpret is None else interpret)
+                               interpret=_interpret(interpret))
 
 
 def topk_threshold_rows(x, k: int, interpret: bool = None):
     """Per-row histogram top-k threshold (N,) for x (N, P)."""
     return _topk_threshold_rows(x, k,
-                                interpret=INTERPRET if interpret is None else interpret)
+                                interpret=_interpret(interpret))
 
 
 def threshold_mask(x, threshold, interpret: bool = None):
     return _threshold_mask(x, threshold,
-                           interpret=INTERPRET if interpret is None else interpret)
+                           interpret=_interpret(interpret))
 
 
 def topk_mask_approx(x, k: int, interpret: bool = None):
     """Histogram-threshold approximate top-k: (values, mask, threshold)."""
-    it = INTERPRET if interpret is None else interpret
+    it = _interpret(interpret)
     t, _, _ = _topk_threshold(x, k, interpret=it)
     vals, mask = _threshold_mask(x, t, interpret=it)
     return vals, mask, t
@@ -106,12 +106,12 @@ def topk_mask_approx(x, k: int, interpret: bool = None):
 
 def ssd_chunk(xdt, Bc, Cc, cum, interpret: bool = None):
     return _ssd_chunk(xdt, Bc, Cc, cum,
-                      interpret=INTERPRET if interpret is None else interpret)
+                      interpret=_interpret(interpret))
 
 
 def swa_attention(q, k, v, window: int, interpret: bool = None):
     return _swa_attention(q, k, v, window,
-                          interpret=INTERPRET if interpret is None else interpret)
+                          interpret=_interpret(interpret))
 
 
 def ssd_scan(xdt, Bc, Cc, cum, interpret: bool = None):

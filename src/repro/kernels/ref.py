@@ -88,23 +88,20 @@ def counter_bits_ref(k1, k2, positions, total: int):
     """uint32 PRF bits at ``positions`` of a ``jax.random.bits(key, (total,))``
     draw, computed positionally (no (total,) materialization).
 
-    Replicates jax's non-partitionable threefry expansion: the counter iota
-    is zero-padded *at the end* to even length S, split into halves
-    x0 = v[:S/2], x1 = v[S/2:], cipher outputs concatenated and truncated
-    back to ``total``.  Elementwise in ``positions``, so a kernel can
-    generate exactly its block's bits.  Bit-identity is asserted in
+    Replicates jax's partitionable threefry expansion
+    (``jax_threefry_partitionable=True``, the default since jax 0.5): the
+    counter of flat position p is the 64-bit word p split into (hi, lo)
+    uint32 halves, and the 32-bit output is the xor of the two cipher
+    words.  For ``total < 2**32`` the hi word is 0, so position p needs
+    only ``threefry(k, 0, p)`` — elementwise in ``positions``, so a kernel
+    can generate exactly its block's bits.  Bit-identity is asserted in
     tests/test_kernels.py against jax.random.bits.
     """
-    total = int(total)
-    s = total + (total % 2)
-    h = s // 2
+    if int(total) >= 1 << 32:
+        raise ValueError(f"counter_bits_ref: total={total} needs a hi counter word")
     q = positions.astype(jnp.uint32)
-    lane = jnp.where(q < h, q, q - jnp.uint32(h))
-    x1_pos = lane + jnp.uint32(h)
-    x0 = lane
-    x1 = jnp.where(x1_pos < total, x1_pos, jnp.uint32(0))
-    y0, y1 = threefry2x32_ref(k1, k2, x0, x1)
-    return jnp.where(q < h, y0, y1)
+    y0, y1 = threefry2x32_ref(k1, k2, jnp.zeros_like(q), q)
+    return y0 ^ y1
 
 
 def secure_mask_apply_nodes_keyed_ref(x, keys, signs, bound):

@@ -9,7 +9,7 @@ receiver's own value, which reduces to a sparse correction:
 
     out[n] = x[n] + sum_s w[n, s] * scatter(idx[n, s], val[n, s] - x[n][idx])
 
-This generalizes ``gossip_mix.gossip_mix_nodes`` (dense (N, K, P) operand
+This generalizes ``gossip_mix.gossip_mix_nodes`` (dense (K, N, P) operand
 stacks) to indexed payloads: O(N·K·k) work instead of O(N·K·P), reading
 x once per P-block.  TPU has no fast VMEM scatter, so the kernel applies
 payload contributions with a broadcast-compare accumulate (idx == column

@@ -69,8 +69,13 @@ def _kernel_nodes(bound_ref, x_ref, bits_ref, signs_ref, o_ref):
     signs = signs_ref[...].astype(jnp.float32)  # (1, K, 1)
     bound = bound_ref[0]
     u01 = (bits >> 8).astype(jnp.float32) * (1.0 / (1 << 24))
-    masks = (u01 * 2.0 - 1.0) * bound
-    o_ref[...] = (x + jnp.sum(masks * signs, axis=1)).astype(o_ref.dtype)
+    masks = (u01 * 2.0 - 1.0) * bound * signs     # (1, K, BN)
+    # slot-by-slot sum, in the keyed kernel's order, so the two agree
+    # bit-for-bit
+    tot = masks[:, 0]
+    for s in range(1, masks.shape[1]):
+        tot = tot + masks[:, s]
+    o_ref[...] = (x + tot).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_n"))
@@ -105,6 +110,11 @@ def secure_mask_apply_nodes(x, bits, signs, bound: float = 1.0, *,
     return out[:, :M]
 
 
+ROWS = 8               # messages per keyed block: the (8, 128) tiling rule
+BLOCK_N_KEYED = 2048   # lanes per keyed block: the cipher's (ROWS, BN) uint32
+#                        temporaries stay a few hundred KiB of VMEM
+
+
 def _threefry2x32(k1, k2, x0, x1):
     """In-kernel Threefry-2x32: uint32 adds/rotates/xors only (VPU ops).
     Must stay bit-identical to kernels.ref.threefry2x32_ref."""
@@ -125,66 +135,73 @@ def _threefry2x32(k1, k2, x0, x1):
     return x0, x1
 
 
-def _kernel_nodes_keyed(bound_ref, x_ref, keys_ref, signs_ref, o_ref, *,
-                        block_n: int, total: int):
-    """One (receiver, param-block) program: expand each pair key's counter
-    bits for this block's positions, map to uniform [-b, b), apply signed.
+def _kernel_nodes_keyed(bound_ref, x_ref, k1_ref, k2_ref, signs_ref, o_ref, *,
+                        block_n: int):
+    """One (message-rows, param-block) program: expand each pair key's
+    counter bits for this block's positions, map to uniform [-b, b), apply
+    signed.
 
-    Positional replication of jax's threefry expansion for a (total,) draw:
-    the counter iota is zero-padded at the end to even length S, halved
-    into cipher lanes (x0 = v[:S/2], x1 = v[S/2:]), outputs concatenated —
-    so position p needs only its own lane pair, computable from p alone.
+    Positional replication of jax's partitionable threefry expansion
+    (see ``kernels.ref.counter_bits_ref``): position p of a draw is
+    ``y0 ^ y1`` of the cipher on counter (0, p), so a block needs only
+    its own positions.  Pair slots run as a static loop over 2-D
+    (ROWS, BN) tiles, each slot's key words a (ROWS, 1) column.
     """
     j = pl.program_id(1)
-    x = x_ref[...].astype(jnp.float32)            # (1, BN)
-    keys = keys_ref[...]                          # (1, K, 2) uint32
-    signs = signs_ref[...].astype(jnp.float32)    # (1, K, 1)
-    bound = bound_ref[0]
-    s = total + (total % 2)
-    h = s // 2
-    q = (jax.lax.broadcasted_iota(jnp.uint32, (1, block_n), 1)
-         + (j * block_n).astype(jnp.uint32))      # global positions
-    lane = jnp.where(q < h, q, q - jnp.uint32(h))
-    x1_pos = lane + jnp.uint32(h)
-    x0 = lane                                     # (1, BN)
-    x1 = jnp.where(x1_pos < total, x1_pos, jnp.uint32(0))
-    k1 = keys[:, :, 0][:, :, None]                # (1, K, 1)
-    k2 = keys[:, :, 1][:, :, None]
-    y0, y1 = _threefry2x32(k1, k2, x0[:, None, :], x1[:, None, :])  # (1, K, BN)
-    bits = jnp.where(q[:, None, :] < h, y0, y1)
-    u01 = (bits >> 8).astype(jnp.float32) * (1.0 / (1 << 24))
-    masks = (u01 * 2.0 - 1.0) * bound
-    o_ref[...] = (x + jnp.sum(masks * signs, axis=1)).astype(o_ref.dtype)
+    x = x_ref[...].astype(jnp.float32)            # (R, BN)
+    k1 = k1_ref[...]                              # (R, K) uint32
+    k2 = k2_ref[...]
+    signs = signs_ref[...].astype(jnp.float32)    # (R, K)
+    bound = bound_ref[...]                        # (1, 1): a vector operand,
+    #                                               no scalar load from VMEM
+    q = jax.lax.convert_element_type(
+        jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) + j * block_n,
+        jnp.uint32)                               # global positions
+    zero = jnp.zeros_like(q)
+    tot = None
+    for s in range(k1.shape[1]):
+        y0, y1 = _threefry2x32(k1[:, s:s + 1], k2[:, s:s + 1], zero, q)
+        m = _uniform(y0 ^ y1, bound) * signs[:, s:s + 1]
+        tot = m if tot is None else tot + m
+    o_ref[...] = (x + tot).astype(o_ref.dtype)
+
+
+def _uniform(bits, bound):
+    """kernels.ref.mask_bits_to_uniform, in-kernel: the top 24 bits fit an
+    int32, whose float conversion the TPU vector unit has."""
+    u01 = jax.lax.convert_element_type(bits >> jnp.uint32(8), jnp.int32)
+    return (u01.astype(jnp.float32) * (1.0 / (1 << 24)) * 2.0 - 1.0) * bound
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_n"))
 def secure_mask_apply_nodes_keyed(x, keys, signs, bound: float = 1.0, *,
-                                  interpret: bool = False, block_n: int = BLOCK_N):
+                                  interpret: bool = False,
+                                  block_n: int = BLOCK_N_KEYED):
     """Fused mask apply with in-kernel bit generation.
 
     x: (B, M) messages; keys: (B, K, 2) uint32 pair-PRF key words
     (``jax.random.key_data`` of the folded-in pair keys); signs: (B, K) in
     {-1, 0, +1} -> (B, M).  Equivalent to staging
     ``jax.random.bits(key, (M,))`` per pair and calling
-    ``secure_mask_apply_nodes`` — without the (B, K, M) bit tensor.
+    ``secure_mask_apply_nodes`` — without the (B, K, M) bit tensor.  Grid
+    (B/ROWS, M/BN); ragged edges are partial blocks (the op is
+    elementwise per position, so out-of-range lanes are dropped).
     """
     B, K, _ = keys.shape
     M = x.shape[1]
-    bn = min(block_n, -(-M // 128) * 128)
-    pad = (-M) % bn
-    xp = jnp.pad(x, ((0, 0), (0, pad)))
-    grid = (B, xp.shape[1] // bn)
-    out = pl.pallas_call(
-        functools.partial(_kernel_nodes_keyed, block_n=bn, total=M),
-        grid=grid,
+    bn, rows = min(block_n, M), min(ROWS, B)
+    row_spec = pl.BlockSpec((rows, K), lambda b, i: (b, 0))
+    return pl.pallas_call(
+        functools.partial(_kernel_nodes_keyed, block_n=bn),
+        grid=(pl.cdiv(B, rows), pl.cdiv(M, bn)),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, i: (0,)),
-            pl.BlockSpec((1, bn), lambda b, i: (b, i)),
-            pl.BlockSpec((1, K, 2), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, K, 1), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, 1), lambda b, i: (0, 0)),
+            pl.BlockSpec((rows, bn), lambda b, i: (b, i)),
+            row_spec, row_spec, row_spec,
         ],
-        out_specs=pl.BlockSpec((1, bn), lambda b, i: (b, i)),
-        out_shape=jax.ShapeDtypeStruct((B, xp.shape[1]), x.dtype),
+        out_specs=pl.BlockSpec((rows, bn), lambda b, i: (b, i)),
+        out_shape=jax.ShapeDtypeStruct((B, M), x.dtype),
         interpret=interpret,
-    )(jnp.asarray(bound, jnp.float32)[None], xp, keys, signs[:, :, None])
-    return out[:, :M]
+        name="secure_mask_keyed",
+    )(jnp.asarray(bound, jnp.float32).reshape(1, 1), x, keys[:, :, 0],
+      keys[:, :, 1], signs)

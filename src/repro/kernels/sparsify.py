@@ -99,54 +99,70 @@ def threshold_mask(x, threshold, *, interpret: bool = False, block: int = BLOCK)
     return vals[:M], mask[:M]
 
 
-def _hist_rows_kernel(x_ref, edges_ref, o_ref):
+ROWS = 8           # rows per block: the (8, 128) tiling rule
+BLOCK_ROWS = 2048  # lanes per block: each row's (E, BN) compare is 1 MiB
+#                    of VMEM at E = 128
+
+
+def _survival_rows_kernel(x_ref, e_ref, o_ref, *, block: int, total: int):
+    """Accumulate c[r, i] = #{p : |x[r, p]| >= edges[r, i]} over the
+    column blocks of a row block.  Edges arrive as (R, E, 1) columns, so
+    each row's compare is an (E, BN) tile reduced along lanes; lanes past
+    ``total`` (a ragged edge block) are masked below every edge."""
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    a = jnp.abs(x_ref[...].astype(jnp.float32))   # (1, B)
-    edges = edges_ref[...].astype(jnp.float32)    # (1, E)
-    idx = jnp.sum(a[0][:, None] >= edges[0][None, :], axis=1)  # (B,) in [0, E]
-    onehot = idx[:, None] == jnp.arange(edges.shape[1] + 1)[None, :]
-    o_ref[...] += jnp.sum(onehot, axis=0).astype(jnp.int32)[None, :]
+    a = jnp.abs(x_ref[...].astype(jnp.float32))   # (R, BN)
+    col = jax.lax.broadcasted_iota(jnp.int32, a.shape, 1) + j * block
+    a = jnp.where(col < total, a, -1.0)
+    for r in range(a.shape[0]):
+        ge = a[r:r + 1, :] >= e_ref[r].astype(jnp.float32)   # (E, BN)
+        o_ref[r] += jnp.sum(ge.astype(jnp.int32), axis=1, keepdims=True)
+
+
+def _survival_rows(x, edges, interpret: bool, block: int = BLOCK_ROWS):
+    """(N, E) int32 survival counts #{|x[n]| >= edges[n, i]}: one pass
+    over x (N, P) with per-row edges (N, E)."""
+    N, P = x.shape
+    E = edges.shape[1]
+    b, rows = min(block, P), min(ROWS, N)
+    c = pl.pallas_call(
+        functools.partial(_survival_rows_kernel, block=b, total=P),
+        grid=(pl.cdiv(N, rows), pl.cdiv(P, b)),
+        in_specs=[
+            pl.BlockSpec((rows, b), lambda n, j: (n, j)),
+            pl.BlockSpec((rows, E, 1), lambda n, j: (n, 0, 0)),
+        ],
+        out_specs=pl.BlockSpec((rows, E, 1), lambda n, j: (n, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((N, E, 1), jnp.int32),
+        interpret=interpret,
+        name="abs_survival_rows",
+    )(x, edges[:, :, None])
+    return c[:, :, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block"))
-def abs_histogram_rows(x, edges, *, interpret: bool = False, block: int = BLOCK):
+def abs_histogram_rows(x, edges, *, interpret: bool = False,
+                       block: int = BLOCK_ROWS):
     """Row-batched |x| histogram: x (N, P), edges (N, E) per-row ascending
-    -> (N, E+1) int32 counts (pad-aware).  Grid (N, P/B): the sharing
-    module's per-node threshold pick is one kernel launch instead of N."""
-    N, P = x.shape
-    b = min(block, -(-P // 128) * 128)
-    pad = (-P) % b
-    xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (0, pad)),
-                 constant_values=jnp.inf)
-    E = edges.shape[1]
-    grid = (N, xp.shape[1] // b)
-    hist = pl.pallas_call(
-        _hist_rows_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, b), lambda n, j: (n, j)),
-            pl.BlockSpec((1, E), lambda n, j: (n, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, E + 1), lambda n, j: (n, 0)),
-        out_shape=jax.ShapeDtypeStruct((N, E + 1), jnp.int32),
-        interpret=interpret,
-    )(xp, edges)
-    return hist - jnp.zeros_like(hist).at[:, E].set(pad)
+    -> (N, E+1) int32 counts, bin i = #{|x| in [edges[i-1], edges[i])}.
+    Grid (N/ROWS, P/B): the sharing module's per-node threshold pick is
+    one kernel launch instead of N.  Built from the survival counts: with
+    ascending edges, bin i holds c[i-1] - c[i]."""
+    c = _survival_rows(x, edges, interpret, block)
+    P = x.shape[1]
+    return jnp.concatenate([P - c[:, :1], c[:, :-1] - c[:, 1:], c[:, -1:]],
+                           axis=1)
 
 
 def _pick_edge_rows(a, k, edges, interpret):
     """Per-row largest edge with #{|x| >= edge} >= k, and the next edge up.
     a: (N, P) magnitudes, edges: (N, E)."""
     nbins = edges.shape[1]
-    hist = abs_histogram_rows(a, edges, interpret=interpret)     # (N, E+1)
-    tail = jnp.cumsum(hist[:, ::-1], axis=1)[:, ::-1]
-    surv = tail[:, 1:]                                           # (N, E)
-    ok = surv >= k
+    ok = _survival_rows(a, edges, interpret) >= k               # (N, E)
     any_ok = ok.any(axis=1)
     pos = (jnp.arange(nbins)[None, :] * ok).argmax(axis=1)       # (N,)
     t = jnp.where(
@@ -157,6 +173,17 @@ def _pick_edge_rows(a, k, edges, interpret):
     return t, t_hi
 
 
+def log_edges_rows(a, nbins: int = NBINS):
+    """(N, nbins) log-spaced per-row edges spanning [1e-7, 1] x max|a[n]|:
+    the coarse bins of :func:`topk_threshold_rows`.  a: (N, P) magnitudes."""
+    hi = jnp.max(a, axis=1)
+    lo = jnp.maximum(hi * 1e-7, 1e-30)
+    span = jnp.linspace(0.0, 1.0, nbins)[None, :]
+    return jnp.exp(
+        jnp.log(lo)[:, None] * (1.0 - span) + jnp.log(jnp.maximum(hi, 1e-30))[:, None] * span
+    )
+
+
 def topk_threshold_rows(x, k: int, nbins: int = NBINS, interpret: bool = False):
     """Per-row histogram top-k threshold: x (N, P) -> t (N,) float32 with
     #{|x[n]| >= t[n]} >= k, within one *fine* bin of exactly k.  The
@@ -164,13 +191,8 @@ def topk_threshold_rows(x, k: int, nbins: int = NBINS, interpret: bool = False):
     refinement discipline), one pass over x per histogram instead of a
     per-row sort — the sharing module's hot-path selector on TPU."""
     a = jnp.abs(x.astype(jnp.float32))
-    hi = jnp.max(a, axis=1)
-    lo = jnp.maximum(hi * 1e-7, 1e-30)
     span = jnp.linspace(0.0, 1.0, nbins)[None, :]
-    edges = jnp.exp(
-        jnp.log(lo)[:, None] * (1.0 - span) + jnp.log(jnp.maximum(hi, 1e-30))[:, None] * span
-    )
-    t0, t0_hi = _pick_edge_rows(a, k, edges, interpret)
+    t0, t0_hi = _pick_edge_rows(a, k, log_edges_rows(a, nbins), interpret)
     fine = t0[:, None] * (1.0 - span) + jnp.maximum(t0_hi, t0 + 1e-30)[:, None] * span
     t1, _ = _pick_edge_rows(a, k, fine, interpret)
     return jnp.maximum(t0, t1)

@@ -26,6 +26,7 @@ from repro.data import make_dataset, sharding_partition
 from repro.models.api import init_params
 from repro.optim import make_optimizer
 from repro.training.trainer import TrainConfig, make_train_step
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def build_lm_batcher(cfg, n_nodes: int, batch: int, seq: int, seed: int = 0):
@@ -142,4 +143,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

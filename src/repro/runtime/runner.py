@@ -248,6 +248,10 @@ class ProcessRunner:
         atomic_write_json(spec_path, spec)
         env = dict(os.environ)
         env["PYTHONPATH"] = _src_root() + os.pathsep + env.get("PYTHONPATH", "")
+        # Workers model the host network path on the host CPU.  An
+        # accelerator belongs to one process: K workers (and this parent,
+        # if it touched jax) must not race for it.
+        env["JAX_PLATFORMS"] = "cpu"
         procs: Dict[int, subprocess.Popen] = {}
         logs = {w: os.path.join(self.run_dir, f"w{w}.log")
                 for w in range(self.workers)}

@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import DLConfig, FaultPlan, RoundEngine
 from repro.core import faults as faults_lib
@@ -179,7 +179,7 @@ class TestEdgeDraws:
 # ---------------------------------------------------------------------------
 
 class TestEdgeReweight:
-    @settings(max_examples=20)
+    @settings(max_examples=20, deadline=None)
     @given(st.integers(min_value=0, max_value=10_000))
     def test_rows_stay_stochastic_under_arbitrary_masks(self, seed):
         """Property: for ANY {0,1} per-edge loss mask, the reweighted dense
@@ -197,7 +197,7 @@ class TestEdgeReweight:
         np.testing.assert_allclose(Wm[kept], W[kept], atol=1e-7)
         assert (Wm[off & (live == 0)] == 0).all()
 
-    @settings(max_examples=10)
+    @settings(max_examples=10, deadline=None)
     @given(st.integers(min_value=0, max_value=10_000))
     def test_sparse_matches_dense(self, seed):
         """edge_reweight_sparse under a slot mask == dense edge_reweight
@@ -221,7 +221,7 @@ class TestEdgeReweight:
 # ---------------------------------------------------------------------------
 
 class TestEdgeReadmitRoundTrip:
-    @settings(max_examples=15)
+    @settings(max_examples=15, deadline=None)
     @given(st.integers(min_value=0, max_value=10_000))
     def test_arbitrary_dead_set_sequences_round_trip_bitwise(self, seed):
         """Property: for ANY sequence of node dead-sets (deaths and

@@ -7,7 +7,7 @@ import numpy as np
 
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.secure import SEED_SHARE_BYTES, SecureAggregation
 from repro.core.sharing import (
@@ -128,7 +128,7 @@ class TestSeedRecovery:
         act[rng.integers(n)] = 1.0
         return jnp.asarray(act)
 
-    @settings(max_examples=8)
+    @settings(max_examples=8, deadline=None)
     @given(st.integers(min_value=0, max_value=10_000))
     def test_dense_recovery_equals_churn_reweighted(self, seed):
         g, X, W = _setup(n=12, degree=4, p=64, seed=seed % 97)
@@ -142,7 +142,7 @@ class TestSeedRecovery:
         np.testing.assert_allclose(np.asarray(X2)[live], want[live],
                                    rtol=5e-4, atol=5e-5)
 
-    @settings(max_examples=8)
+    @settings(max_examples=8, deadline=None)
     @given(st.integers(min_value=0, max_value=10_000))
     def test_sparse_recovery_matches_dense_oracle(self, seed):
         g, X, W = _setup(n=12, degree=4, p=64, seed=seed % 89)

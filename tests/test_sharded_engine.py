@@ -267,8 +267,7 @@ class TestMixSparseShmap:
             st = SparseTopology.from_graph(g)
             t = {"a": jax.random.normal(jax.random.key(0), (n, 5, 3)),
                  "b": jax.random.normal(jax.random.key(1), (n, 9))}
-            ref = mix_sparse(t, jax.tree_util.tree_map(jnp.asarray, st),
-                             use_pallas=False)
+            ref = mix_sparse(t, jax.tree_util.tree_map(jnp.asarray, st))
             out = jax.jit(
                 lambda x: mix_sparse_shmap(x, st, mesh, ("data",), backend=backend)
             )(t)

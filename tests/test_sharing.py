@@ -3,7 +3,7 @@ byte accounting."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.sharing import (
     ChocoSGD,

@@ -5,10 +5,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import DLConfig, RoundEngine
-from repro.core.mixing import apply_W, mix_dense, mix_sparse
+from repro.core.mixing import (
+    apply_W, gather_mix, gossip_operands, mix_dense, mix_sparse,
+)
 from repro.core.secure import SecureAggregation
 from repro.core.sharing import (
     make_sharing,
@@ -84,7 +86,7 @@ class TestMixSparseEquivalence:
         t = {"a": jax.random.normal(k1, (g.n, 7, 3)),
              "b": jax.random.normal(k2, (g.n, 11))}
         d = mix_dense(t, W)
-        s = mix_sparse(t, st_, use_pallas=False)
+        s = mix_sparse(t, st_)
         for l1, l2 in zip(jax.tree_util.tree_leaves(d), jax.tree_util.tree_leaves(s)):
             np.testing.assert_allclose(np.asarray(l1), np.asarray(l2),
                                        rtol=2e-5, atol=2e-6)
@@ -93,8 +95,8 @@ class TestMixSparseEquivalence:
         g = Graph.regular_circulant(16, 5)
         st_ = _dev(SparseTopology.from_graph(g))
         t = {"a": jax.random.normal(jax.random.key(1), (16, 300))}
-        a = mix_sparse(t, st_, use_pallas=False)["a"]
-        b = mix_sparse(t, st_, use_pallas=True, interpret=True)["a"]
+        a = gather_mix(st_, t["a"])
+        b = ops.gossip_mix_nodes(*gossip_operands(st_, t["a"]), interpret=True)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=2e-5, atol=2e-6)
 
     @given(st.integers(0, 10))
@@ -198,7 +200,7 @@ class TestBatchedKernels:
     def test_gossip_mix_nodes(self, B, K, M, dtype):
         nb = jax.random.normal(jax.random.key(B * M), (B, K, M), jnp.float32).astype(dtype)
         w = jax.random.uniform(jax.random.key(1), (B, K))
-        got = ops.gossip_mix_nodes(nb, w)
+        got = ops.gossip_mix_nodes(jnp.swapaxes(nb, 0, 1), w)  # slot-major
         want = ref.gossip_mix_nodes_ref(nb, w)
         tol = 1e-5 if dtype == jnp.float32 else 1e-2
         np.testing.assert_allclose(np.asarray(got, np.float32),

@@ -135,7 +135,7 @@ class TestRooflineParser:
             import jax, jax.numpy as jnp
             from jax.sharding import PartitionSpec as P
             from repro.launch.roofline import parse_collective_bytes
-            from repro.utils.compat import shard_map
+            from jax import shard_map
             mesh = jax.make_mesh((4,), ("d",))
             def f(x):
                 y = jax.lax.psum(x, "d")

@@ -2,7 +2,7 @@
 file I/O, runtime mutation."""
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.topology import Graph, PeerSampler, circulant_offsets
 
