@@ -63,6 +63,7 @@ from repro.core.sharing import (
 from repro.core.steps import node_where
 from repro.core.topology import SparseTopology, gather_rows, sample_neighbor_slots
 from repro.utils.pytree import tree_unvector, tree_vector
+from repro.utils.spans import HostSpans
 
 # cap on the pre-gathered (R, L, N, B, ...) batch stack; above it the scan
 # falls back to gathering each round's batch inside the loop body.
@@ -85,6 +86,13 @@ _REBASE_T_S = 65536.0
 # O(C·(d+1)·P) gossip, above it the O(N) selection layer starts to bind
 # (the million-node regime the hierarchy exists for).
 _HIER_AUTO_MIN_N = 1 << 18
+
+
+@jax.jit
+def stage_batches(x, y, idx):
+    """A chunk's pre-gathered batches ``(x[idx], y[idx])`` as one program
+    (``jit_stage_batches`` in a profile)."""
+    return jnp.take(x, idx, axis=0), jnp.take(y, idx, axis=0)
 
 
 def _live_edges(W, act):
@@ -127,6 +135,9 @@ class Scheduler:
         self._track_faults = eng.dl.faults is not None or (
             eng.dl.secure and eng.dl.secure_recovery
         )
+        # host seconds by span: run_span > stage (> stage.batches,
+        # stage.graphs), dispatch, sync; counts["run_span"] is the chunks
+        self.host_s = HostSpans()
 
     # ------------------------------------------------------------------
     # activation masks (churn)
@@ -182,52 +193,64 @@ class Scheduler:
         ``by`` under the byte cap, raw ``idx`` above it; plus ``mix`` for
         dynamic topologies ((R,N,N) W stack in dense mode, (R,N,D)
         SparseTopology stack in sparse mode) and ``act`` (R,N) with
-        churn."""
+        churn.  Runs as the ``stage`` span, whose ``bytes`` stat is what
+        it copies from host to device."""
         eng = self.eng
         dl = eng.dl
-        xs = {"rnd": jnp.asarray(np.arange(start, start + n_rounds, dtype=np.int32))}
-        if not self._node_keying:
-            idx = eng.batcher.chunk_indices(start, n_rounds, dl.local_steps)
-            item_bytes = eng._dev_x.nbytes // max(eng._dev_x.shape[0], 1)
-            if idx.size * item_bytes <= _BATCH_STACK_BYTES_CAP:
-                # pre-stack the whole chunk's batches on device: one gather
-                # per chunk instead of one per scanned round
-                idx_dev = jnp.asarray(idx)
-                xs["bx"] = jnp.take(eng._dev_x, idx_dev, axis=0)  # (R, L, N, B, ...)
-                xs["by"] = jnp.take(eng._dev_y, idx_dev, axis=0)
-            else:
-                xs["idx"] = jnp.asarray(idx)
-        # ('node' keying stages nothing: each scan step derives its rows'
-        # indices from (rnd, id) in-body — see _node_indices)
-        if eng.sampler is not None:
-            if eng.mix_mode == "sparse":
-                st = eng.sampler.sparse_stack(start, n_rounds)  # (R, N, D)
-                xs["mix"] = SparseTopology(
-                    jnp.asarray(st.nbr), jnp.asarray(st.w), jnp.asarray(st.w_self)
-                )
-                staged = st.stage_bytes()
-            else:
-                Wst = eng.sampler.weights_stack(start, n_rounds)  # (R, N, N)
-                xs["mix"] = jnp.asarray(Wst)
-                staged = int(Wst.nbytes)
-            eng.topo_stage_bytes_peak = max(eng.topo_stage_bytes_peak, staged)
-        plan = dl.faults
-        crashes = plan is not None and bool(plan.crashes)
-        if dl.participation < 1.0 or crashes:
-            m = self.participation_mask(start, n_rounds)
-            if crashes:
-                # declarative crash/restart windows AND into the churn
-                # draw: a crashed node is exactly a churn-down node, but
-                # deterministic (both masks are pure functions of the
-                # absolute round, so chunking stays invariant)
-                cm = faults_lib.crash_mask(plan, dl.n_nodes, start, n_rounds)
-                m = m * cm
-                # crash downtime counts as injected faults absorbed by the
-                # participation machinery (frozen state, reweighted mixing)
-                down = float((1.0 - cm).sum())
-                self._fault_totals["faults_injected"] += down
-                self._fault_totals["faults_survived"] += down
-            xs["act"] = jnp.asarray(m)
+        spans = self.host_s
+        with spans.span("stage") as stage:
+            rnd = np.arange(start, start + n_rounds, dtype=np.int32)
+            staged = rnd.nbytes
+            xs = {"rnd": jnp.asarray(rnd)}
+            if not self._node_keying:
+                with spans.span("stage.batches"):
+                    idx = eng.batcher.chunk_indices(start, n_rounds, dl.local_steps)
+                    staged += idx.nbytes
+                    item_bytes = eng._dev_x.nbytes // max(eng._dev_x.shape[0], 1)
+                    if idx.size * item_bytes <= _BATCH_STACK_BYTES_CAP:
+                        # pre-stack the whole chunk's batches on device: one
+                        # gather per chunk instead of one per scanned round
+                        xs["bx"], xs["by"] = stage_batches(
+                            eng._dev_x, eng._dev_y, jnp.asarray(idx)
+                        )  # (R, L, N, B, ...)
+                    else:
+                        xs["idx"] = jnp.asarray(idx)
+            # ('node' keying stages nothing: each scan step derives its rows'
+            # indices from (rnd, id) in-body — see _node_indices)
+            if eng.sampler is not None:
+                with spans.span("stage.graphs"):
+                    if eng.mix_mode == "sparse":
+                        st = eng.sampler.sparse_stack(start, n_rounds)  # (R, N, D)
+                        xs["mix"] = SparseTopology(
+                            jnp.asarray(st.nbr), jnp.asarray(st.w),
+                            jnp.asarray(st.w_self),
+                        )
+                        graph_bytes = st.stage_bytes()
+                    else:
+                        Wst = eng.sampler.weights_stack(start, n_rounds)  # (R, N, N)
+                        xs["mix"] = jnp.asarray(Wst)
+                        graph_bytes = int(Wst.nbytes)
+                staged += graph_bytes
+                eng.topo_stage_bytes_peak = max(eng.topo_stage_bytes_peak, graph_bytes)
+            plan = dl.faults
+            crashes = plan is not None and bool(plan.crashes)
+            if dl.participation < 1.0 or crashes:
+                m = self.participation_mask(start, n_rounds)
+                if crashes:
+                    # declarative crash/restart windows AND into the churn
+                    # draw: a crashed node is exactly a churn-down node, but
+                    # deterministic (both masks are pure functions of the
+                    # absolute round, so chunking stays invariant)
+                    cm = faults_lib.crash_mask(plan, dl.n_nodes, start, n_rounds)
+                    m = m * cm
+                    # crash downtime counts as injected faults absorbed by the
+                    # participation machinery (frozen state, reweighted mixing)
+                    down = float((1.0 - cm).sum())
+                    self._fault_totals["faults_injected"] += down
+                    self._fault_totals["faults_survived"] += down
+                staged += m.nbytes
+                xs["act"] = jnp.asarray(m)
+            stage.set_metadata(bytes=staged)
         return xs
 
     def _node_indices(self, rnd, ids):
@@ -258,6 +281,26 @@ class Scheduler:
 
     # ------------------------------------------------------------------
     def run_span(self, start: int, n_rounds: int) -> None:
+        """Rounds [start, start+n_rounds) as one chunk: stage its inputs,
+        dispatch the scanned program, then pull its per-round metrics to
+        the host — the chunk's one host sync, which waits for the device.
+        Each phase is a host span (``dl.stage``, ``dl.dispatch``,
+        ``dl.sync``) inside ``dl.run_span``."""
+        spans = self.host_s
+        with spans.span("run_span", rnd=start):
+            xs = self._stage_xs(start, n_rounds)
+            with spans.span("dispatch"):
+                metrics = self._dispatch(xs)
+            with spans.span("sync"):
+                self._pull(metrics)
+
+    def _dispatch(self, xs):
+        """Run the chunk on ``xs``, keep its end state; return its
+        per-round metric outputs (still on the device)."""
+        raise NotImplementedError
+
+    def _pull(self, metrics) -> None:
+        """Fold a chunk's metric outputs into the host totals."""
         raise NotImplementedError
 
     def run_legacy_round(self, rnd: int) -> None:
@@ -268,7 +311,11 @@ class Scheduler:
 
     def _accum_faults(self, fstats) -> None:
         """Fold one dispatch's fstats (dict of (R,) stacked arrays, or
-        scalars from the legacy path) into the host float64 totals."""
+        scalars from the legacy path) into the host float64 totals —
+        only where a fault axis is active: nothing reads them otherwise,
+        and each is a host pull."""
+        if not self._track_faults:
+            return
         for k in faults_lib.STAT_KEYS:
             self._fault_totals[k] += float(
                 np.asarray(fstats[k], np.float64).sum()
@@ -438,15 +485,19 @@ class SyncScheduler(Scheduler):
         return fn(eng.params, eng.opt_state, eng.share_state, xs)
 
     # -- host-side dispatch ----------------------------------------------
-    def run_span(self, start: int, n_rounds: int) -> None:
+    def _dispatch(self, xs):
         eng = self.eng
-        xs = self._stage_xs(start, n_rounds)
         if eng.sharded:
             out = self._sharded_chunk_call(xs)
         else:
             out = self._chunk_jit(eng.params, eng.opt_state, eng.share_state, xs)
-        eng.params, eng.opt_state, eng.share_state, nbytes, times, fstats = out
+        eng.params, eng.opt_state, eng.share_state = out[:3]
+        return out[3:]
+
+    def _pull(self, metrics) -> None:
         # ONE host sync per chunk for all per-round metrics
+        eng = self.eng
+        nbytes, times, fstats = metrics
         eng.bytes_sent += float(np.asarray(nbytes, np.float64).sum())
         eng.sim_time_s += float(np.asarray(times, np.float64).sum())
         self._accum_faults(fstats)
@@ -542,14 +593,17 @@ class LocalScheduler(Scheduler):
         )
         return carry + (nbytes, times, fstats)
 
-    def run_span(self, start: int, n_rounds: int) -> None:
+    def _dispatch(self, xs):
         eng = self.eng
-        xs = self._stage_xs(start, n_rounds)
         out = self._chunk_jit(
             eng.params, eng.opt_state, eng.share_state, self._clock, xs
         )
-        (eng.params, eng.opt_state, eng.share_state, self._clock,
-         nbytes, times, fstats) = out
+        eng.params, eng.opt_state, eng.share_state, self._clock = out[:4]
+        return out[4:]
+
+    def _pull(self, metrics) -> None:
+        eng = self.eng
+        nbytes, times, fstats = metrics
         eng.bytes_sent += float(np.asarray(nbytes, np.float64).sum())
         # the virtual clock is a running maximum, not a per-round sum
         eng.sim_time_s = float(np.asarray(times)[-1])
@@ -1219,9 +1273,8 @@ class AsyncScheduler(Scheduler):
         return carry + (None,) + outs
 
     # -- host-side dispatch ----------------------------------------------
-    def run_span(self, start: int, n_rounds: int) -> None:
+    def _dispatch(self, xs):
         eng = self.eng
-        xs = self._stage_xs(start, n_rounds)
         out = self._chunk_jit(
             eng.params, eng.opt_state, eng.share_state,
             self._t_next, self._vclock, self._events, self._retries,
@@ -1230,7 +1283,11 @@ class AsyncScheduler(Scheduler):
         (eng.params, eng.opt_state, eng.share_state,
          self._t_next, self._vclock, self._events, self._retries) = out[:7]
         self._seg_min = out[7]
-        nbytes, t_virt, fired, stale_sum, stale_n, stale_max = out[8:14]
+        return out[8:]
+
+    def _pull(self, metrics) -> None:
+        eng = self.eng
+        nbytes, t_virt, fired, stale_sum, stale_n, stale_max = metrics[:6]
         eng.bytes_sent += float(np.asarray(nbytes, np.float64).sum())
         # the virtual clock is a running maximum, not a per-cohort sum —
         # fp32-exact (max selects, never rounds) — plus the rebase offset
@@ -1240,13 +1297,13 @@ class AsyncScheduler(Scheduler):
         self._stale_n += float(np.asarray(stale_n, np.float64).sum())
         self._stale_max = max(self._stale_max, float(np.asarray(stale_max).max()))
         if self._cohort_c > 0:
-            occ = np.asarray(out[14], np.float64)
+            occ = np.asarray(metrics[6], np.float64)
             self._occ_sum += float(occ.sum())
             self._occ_steps += int(occ.shape[0])
-            self._overflow_total += int(np.asarray(out[15], np.int64).sum())
-            self._fallback_total += int(np.asarray(out[16], np.int64).sum())
+            self._overflow_total += int(np.asarray(metrics[7], np.int64).sum())
+            self._fallback_total += int(np.asarray(metrics[8], np.int64).sum())
         else:
-            self._accum_faults(out[14])
+            self._accum_faults(metrics[6])
         self._maybe_rebase()
 
     def _maybe_rebase(self) -> None:

@@ -241,7 +241,12 @@ class RoundSteps:
         if guard:
             snap = (params, opt_state, share_state)  # last-good snapshot
         key = jax.random.fold_in(self.base_key, rnd)
-        params, opt_state = self.local_train(params, opt_state, bx, by, active, shard)
+        # named scopes mark the round's layers in the compiled program's
+        # op metadata, where a profile attributes device time to them
+        with jax.named_scope("local_step"):
+            params, opt_state = self.local_train(
+                params, opt_state, bx, by, active, shard
+            )
         if active is not None:
             if isinstance(W, ShardedTopology):
                 t2, deg_eff = participation_reweight_sparse(
@@ -287,13 +292,15 @@ class RoundSteps:
             # delivery: survived by design, never silently lost
             fstats["faults_injected"] += dropped + spiked
             fstats["faults_survived"] += dropped + spiked
-        X = jax.vmap(tree_vector)(params)
+        with jax.named_scope("flatten"):
+            X = jax.vmap(tree_vector)(params)
         share_kw = {}
         if getattr(self.sharing, "needs_act", False) and active is not None:
             share_kw["act"] = active
-        X2, new_share, nbytes = self.sharing.round(
-            X, Wm_mix, share_state, key, degree=deg_eff, rnd=rnd, **share_kw
-        )
+        with jax.named_scope("share_mix"):
+            X2, new_share, nbytes = self.sharing.round(
+                X, Wm_mix, share_state, key, degree=deg_eff, rnd=rnd, **share_kw
+            )
         if share_kw:
             rec = self._secure_recovery_bytes(active, shard)
             nbytes = nbytes + rec
@@ -314,7 +321,8 @@ class RoundSteps:
             share_state = node_where(active, new_share, share_state)
         else:
             share_state = new_share
-        new_params = jax.vmap(lambda v: tree_unvector(v, self.template))(X2)
+        with jax.named_scope("unflatten"):
+            new_params = jax.vmap(lambda v: tree_unvector(v, self.template))(X2)
         if active is not None:
             # don't trust each strategy's W-row-identity property for down
             # nodes (e.g. QuantizedSharing would hand them the int8
