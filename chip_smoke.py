@@ -273,12 +273,12 @@ def _check_topk(eng):
 
 
 def _check_secure(eng):
-    """(1) The fused keyed-mask kernel on 32 real node rows vs
-    kernels.ref.secure_mask_apply_nodes_keyed_ref: the masks are the same
-    bits mapped the same way, so only the order of the 5 signed terms
-    (|term| <= bound = 1) differs — bound 4e-6, about 5 * 5 ulps of the
-    sum.  (2) The masked round vs the unmasked Metropolis-Hastings round
-    W @ X, at the fp32 tolerance tests/test_secure.py holds it to."""
+    """(1) The fused keyed-mask kernel on D slots of 32 real node rows vs
+    kernels.ref.secure_mask_apply_pairs_keyed_ref: the masks are the same
+    bits mapped the same way, so only the order of the 4 signed terms of
+    a message (|term| <= bound = 1) differs — bound 4e-6, about 5 * 5 ulps
+    of the sum.  (2) The masked round vs the unmasked Metropolis-Hastings
+    round W @ X, at the fp32 tolerance tests/test_secure.py holds it to."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -288,14 +288,17 @@ def _check_secure(eng):
 
     X = jax.vmap(tree_vector)(eng.params)
     rows, D = min(32, X.shape[0]), eng.dl.degree
-    keys = jax.random.bits(jax.random.key(1), (rows, D, 2), jnp.uint32)
-    signs = jnp.asarray(np.random.default_rng(2).choice([-1.0, 0.0, 1.0], (rows, D)),
+    Q = D * (D - 1) // 2
+    xs = jnp.take(X, (np.arange(rows)[None] + rows * np.arange(D)[:, None]) % X.shape[0],
+                  axis=0)                                  # (D, rows, P)
+    keys = jax.random.bits(jax.random.key(1), (rows, Q, 2), jnp.uint32)
+    signs = jnp.asarray(np.random.default_rng(2).choice([-1.0, 0.0, 1.0], (rows, D, D)),
                         jnp.float32)
-    got = ops.secure_mask_apply_nodes_keyed(X[:rows], keys, signs, 1.0)
+    got = ops.secure_mask_apply_pairs_keyed(xs, keys, signs, 1.0)
     with jax.default_matmul_precision("highest"):
-        want = jax.jit(ref.secure_mask_apply_nodes_keyed_ref, static_argnums=3)(
-            X[:rows], keys, signs, 1.0)
-    out = [_close("secure_mask_apply_nodes_keyed vs ref", got, want, 4e-6)]
+        want = jax.jit(ref.secure_mask_apply_pairs_keyed_ref, static_argnums=3)(
+            xs, keys, signs, 1.0)
+    out = [_close("secure_mask_apply_pairs_keyed vs ref", got, want, 4e-6)]
 
     W = eng._mix_static
     masked, _, _ = jax.jit(
@@ -324,6 +327,9 @@ def run_one_chip(size, cache, on_tpu):
         eng = _engine(size, workload, **kw)
         line = {"phase": name, "n_nodes": size.nodes, "n_params": eng.n_params}
         line.update(_drive(eng, cache))
+        if name == "secure":  # the mask kernel's work: with the round rate, its words/s
+            line["prf_words_per_round"] = eng.sharing.prf_words_per_round(
+                size.nodes, eng.n_params)
         dev = jax.devices()[0]
         line["bytes_in_use"] = _mem(dev, "bytes_in_use")
         line["peak_bytes_in_use_so_far"] = _mem(dev, "peak_bytes_in_use")

@@ -20,16 +20,17 @@ Two implementations of the same math:
 
 * ``round``            — vectorized and fully jittable: the ragged neighbor
   sets become a padded ``(N, dmax)`` neighbor table (topology.neighbor_table),
-  batched vmap passes derive the per-pair threefry PRF *keys* (one sender
-  slot at a time via lax.map — O(N·d) key words staged, the bit tensors
+  one batched vmap pass derives the threefry PRF *keys* of every
+  receiver's co-neighbor pairs (O(N·d²) key words staged, the bit tensors
   never materialize; round index a *traced* value), and the fused
   ``kernels/secure_mask`` keyed Pallas kernel (compiled on TPU, interpret
-  mode on CPU) runs the threefry counter expansion in-body, maps
-  bits→uniform, and applies all signed masks in one HBM pass — bit-identical
-  to expanding ``jax.random.bits`` per pair.
+  mode on CPU) runs the threefry counter expansion in-body, once per
+  unordered pair, maps bits→uniform, and adds each mask with opposite
+  signs to the two messages that carry it, in one HBM pass — bit-identical
+  to expanding ``jax.random.bits`` per (message, pair).
   So ``secure=True`` runs inside the engine's lax.scan chunk like any other
-  sharing strategy; work is O(N·d²·P) like the reference, without the
-  O(N·d) Python dict of messages or the former per-slot fori_loop.
+  sharing strategy; it expands N·d(d-1)/2·P cipher words a round
+  (``prf_words_per_round``), without the O(N·d) Python dict of messages.
 * ``round_reference``  — the original Python dict-of-messages schedule, kept
   as the oracle the vectorized path is equivalence-tested against.  Both
   paths derive masks from the same threefry bits via the same
@@ -56,6 +57,7 @@ from repro.core.mixing import ShardedDense, ShardedTopology
 from repro.core.topology import SparseTopology, neighbor_table
 from repro.kernels import ops as kernel_ops
 from repro.kernels.ref import mask_bits_to_uniform
+from repro.kernels.secure_mask import slot_pairs
 
 BYTES_VAL = 4
 METADATA_OVERHEAD = 0.03  # paper: ~3% extra bytes (seeds, framing)
@@ -162,16 +164,15 @@ class SecureAggregation:
         seed-recovery pass and the live neighbor set is aggregated with
         the churn-reweighted weights W already carries.
 
-        Pipeline, per sender slot (lax.map over the D slots): (1) a batched
-        vmap pass derives the threefry *pair keys* of every (receiver,
-        co-neighbor) mask for that slot's messages — O(N·d) key words, not
-        O(N·d·P) bit tensors; keys are built from the *sorted* node pair so
-        the +1 and -1 occurrences expand identical bits and cancel exactly;
-        (2) the fused Pallas kernel (``secure_mask_apply_nodes_keyed``)
-        runs the threefry counter expansion in-body per parameter block,
-        maps bits -> uniform[-b, b), and applies all signed masks to the
-        slot's N messages in one HBM pass.  Finally each receiver sums its
-        valid masked messages with weight w.
+        Pipeline: (1) one vmap pass over (receiver, co-neighbor slot pair)
+        derives the threefry *pair keys* — O(N·d²) key words, not O(N·d·P)
+        bit tensors; keys are built from the *sorted* node pair, so the
+        pair's +1 and -1 occurrences are one mask and cancel exactly; (2)
+        the fused Pallas kernel (``secure_mask_apply_pairs_keyed``) runs the
+        threefry counter expansion in-body per parameter block, once per
+        pair, maps bits -> uniform[-b, b), and adds each mask, signed, to
+        both messages that carry it — all D·N messages in one HBM pass.
+        Finally each receiver sums its valid masked messages with weight w.
         """
         if isinstance(W, (ShardedTopology, ShardedDense)):
             return self._round_sharded(X, W, state, key, degree, rnd, act)
@@ -234,7 +235,7 @@ class SecureAggregation:
 
     def _masked_aggregate(self, Xf, Xnbr, nbr, validf, wvec, rows, key, rnd,
                           degree, dtype, state, act_nbr=None):
-        """Shared core of the vectorized path: per-slot PRF bits + fused
+        """Shared core of the vectorized path: pair PRF keys + fused
         mask apply + weighted receiver sum.  ``rows`` are the global node
         ids of the local receiver rows (arange unsharded).  ``Xnbr`` is
         the slot-major (D, N, P) neighbor stack: on TPU a node-major
@@ -249,42 +250,28 @@ class SecureAggregation:
         pairs, and the receiver aggregates the live slots only — equal to
         the churn-reweighted plain aggregate."""
         P = Xf.shape[1]
-        D = nbr.shape[1]
         kr = jax.random.fold_in(key, rnd)
         i_mat = nbr[:, :, None]                            # sender node
         j_mat = nbr[:, None, :]                            # co-neighbor node
-        signs = (
-            jnp.where(i_mat < j_mat, 1.0, -1.0)
-            * validf[:, None, :]
-            * (1.0 - jnp.eye(D, dtype=jnp.float32))
-        )                                                  # (N, D, D)
+        signs = jnp.where(i_mat < j_mat, 1.0, -1.0) * validf[:, None, :]  # (N, D, D)
+        lo, hi = slot_pairs(nbr.shape[1])                  # slot pairs s < t
 
-        def slot_pass(base, signs_all):
-            def slot_msgs(ii):
-                def receiver_keys(r, nbr_r):
-                    i = nbr_r[ii]
+        def receiver_keys(r, nbr_r):
+            def pair(i, j):
+                a, b = jnp.minimum(i, j), jnp.maximum(i, j)
+                return jax.random.key_data(_pair_key_from(kr, a, b, r))
 
-                    def pair(j):
-                        a, b = jnp.minimum(i, j), jnp.maximum(i, j)
-                        return jax.random.key_data(_pair_key_from(kr, a, b, r))
+            return jax.vmap(pair)(nbr_r[lo], nbr_r[hi])    # (Q, 2)
 
-                    return jax.vmap(pair)(nbr_r)           # (D, 2)
+        keys = jax.vmap(receiver_keys)(rows, nbr)          # (N, Q, 2) uint32
 
-                keys = jax.vmap(receiver_keys)(rows, nbr)  # (N, D, 2) uint32
-                return kernel_ops.secure_mask_apply_nodes_keyed(
-                    jnp.take(base, ii, axis=0),
-                    keys,
-                    jnp.take(signs_all, ii, axis=1),
-                    self.mask_bound,
-                )                                          # (N, P)
-
-            return jax.lax.map(slot_msgs, jnp.arange(D))   # (D, N, P)
-
-        msgs = slot_pass(Xnbr, signs)
+        msgs = kernel_ops.secure_mask_apply_pairs_keyed(
+            Xnbr, keys, signs, self.mask_bound)            # (D, N, P)
         validf_live = validf
         if act_nbr is not None:
             down = validf * (1.0 - act_nbr)                # dropped co-nbrs
-            msgs = slot_pass(msgs, -signs * down[:, None, :])
+            msgs = kernel_ops.secure_mask_apply_pairs_keyed(
+                msgs, keys, -signs * down[:, None, :], self.mask_bound)
             validf_live = validf * act_nbr
         deg_r = validf_live.sum(1)
         acc = (1.0 - wvec * deg_r)[:, None] * Xf + wvec[:, None] * jnp.sum(
@@ -301,6 +288,13 @@ class SecureAggregation:
     def stage_bytes_per_round(self, n: int, p: int) -> int:
         # recovery stages a second full mask pass over the neighbor stack
         return n * p * 4 * (2 if self.recovery else 1)
+
+    def prf_words_per_round(self, n: int, p: int) -> int:
+        """Threefry words a round expands: one per position of each
+        receiver's D(D-1)/2 co-neighbor pair masks, each mask shared by the
+        two messages that carry it; recovery repeats the pass."""
+        d = self._nbr.shape[1]
+        return n * (d * (d - 1) // 2) * p * (2 if self.recovery else 1)
 
     def round_reference(self, X, W, state, key, degree: float, rnd: int = 0):
         """Python-scheduled reference: aggregate the dict of masked

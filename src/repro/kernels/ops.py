@@ -19,7 +19,7 @@ from repro.kernels.scatter_gossip import payload_mix_nodes as _payload_mix_nodes
 from repro.kernels.secure_mask import secure_mask_apply as _secure_mask_apply
 from repro.kernels.secure_mask import secure_mask_apply_nodes as _secure_mask_apply_nodes
 from repro.kernels.secure_mask import (
-    secure_mask_apply_nodes_keyed as _secure_mask_apply_nodes_keyed,
+    secure_mask_apply_pairs_keyed as _secure_mask_apply_pairs_keyed,
 )
 from repro.kernels.sparsify import abs_histogram as _abs_histogram
 from repro.kernels.sparsify import abs_histogram_rows as _abs_histogram_rows
@@ -63,10 +63,10 @@ def secure_mask_apply_nodes(x, bits, signs, bound: float = 1.0, interpret: bool 
                                     interpret=_interpret(interpret))
 
 
-def secure_mask_apply_nodes_keyed(x, keys, signs, bound: float = 1.0,
+def secure_mask_apply_pairs_keyed(xs, keys, signs, bound: float = 1.0,
                                   interpret: bool = None):
-    return _secure_mask_apply_nodes_keyed(
-        x, keys, signs, bound,
+    return _secure_mask_apply_pairs_keyed(
+        xs, keys, signs, bound,
         interpret=_interpret(interpret))
 
 

@@ -4,6 +4,9 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from repro.kernels.secure_mask import slot_pairs
 
 
 def gossip_mix_ref(neighbors, weights):
@@ -118,6 +121,32 @@ def secure_mask_apply_nodes_keyed_ref(x, keys, signs, bound):
         x.astype(jnp.float32)
         + jnp.einsum("bk,bkm->bm", signs.astype(jnp.float32), masks)
     ).astype(x.dtype)
+
+
+def pairs_to_slots(keys, signs):
+    """The pair layout of ``secure_mask_apply_pairs_keyed`` as per-message
+    slots: keys (B, Q, 2) of the slot pairs in ``slot_pairs`` order and
+    signs (B, D, D) -> keys (D, B, D, 2) and signs (D, B, D), message s's
+    slot t holding pair {s, t}'s key and signs[:, s, t], and its own slot
+    s a zero-signed placeholder key."""
+    D = signs.shape[1]
+    lo, hi = slot_pairs(D)
+    pair = np.zeros((D, D), np.int32)
+    pair[lo, hi] = pair[hi, lo] = np.arange(len(lo))
+    own = jnp.asarray(1.0 - np.eye(D), signs.dtype)
+    return jnp.moveaxis(keys[:, pair], 1, 0), jnp.moveaxis(signs * own, 1, 0)
+
+
+def secure_mask_apply_pairs_keyed_ref(xs, keys, signs, bound):
+    """xs: (D, B, M); keys: (B, Q, 2); signs: (B, D, D) -> (D, B, M): each
+    message masked by ``secure_mask_apply_nodes_keyed_ref`` over its slots
+    as ``pairs_to_slots`` lays them out."""
+    D = xs.shape[0]
+    if D == 1:
+        return xs
+    k, sg = pairs_to_slots(keys, signs)
+    return jnp.stack([secure_mask_apply_nodes_keyed_ref(xs[s], k[s], sg[s], bound)
+                      for s in range(D)])
 
 
 def payload_mix_nodes_ref(x, idx, val, w):

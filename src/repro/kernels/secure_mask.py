@@ -10,12 +10,16 @@ Two bit sources:
 * ``secure_mask_apply`` / ``secure_mask_apply_nodes`` — bits produced
   outside (threefry) and staged as (…, K, M) uint32 tensors: simple, but
   the caller pays O(B·K·M) HBM for the bit stacks.
-* ``secure_mask_apply_nodes_keyed`` — the fused form: the caller passes
-  only the (B, K, 2) uint32 *pair keys* and the kernel runs the
+* ``secure_mask_apply_pairs_keyed`` — the fused form: the caller passes
+  only the (B, Q, 2) uint32 *pair keys* of each receiver's Q = d(d-1)/2
+  co-neighbor pairs, in ``slot_pairs`` order, and the kernel runs the
   Threefry-2x32 counter expansion in-body per block, bit-identical to
   ``jax.random.bits(key, (M,))`` (asserted against
-  ``kernels.ref.counter_bits_ref``).  Peak staging for a secure round
-  drops from O(N·d·P) bits to O(N·d) keys.
+  ``kernels.ref.counter_bits_ref``).  Receiver-major: pair {i, j}'s mask
+  at receiver r rides in message i->r with one sign and in j->r with the
+  other, so it is expanded once and added to both — N·Q·P cipher words a
+  round, not N·d²·P.  Peak staging for a secure round drops from
+  O(N·d·P) bits to O(N·Q) keys.
 """
 from __future__ import annotations
 
@@ -23,6 +27,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
 BLOCK_N = 65536
@@ -110,9 +115,18 @@ def secure_mask_apply_nodes(x, bits, signs, bound: float = 1.0, *,
     return out[:, :M]
 
 
-ROWS = 8               # messages per keyed block: the (8, 128) tiling rule
-BLOCK_N_KEYED = 2048   # lanes per keyed block: the cipher's (ROWS, BN) uint32
-#                        temporaries stay a few hundred KiB of VMEM
+ROWS = 8                # receivers per keyed block: the (8, 128) tiling rule
+BLOCK_SUB = 1024        # lanes per inner step of the keyed kernel: the
+#                         fastest of 256 to 8192 on a TPU v5e (PERF.md)
+BLOCK_BYTES = 1 << 20   # one (D, ROWS, bn) float32 message block of the keyed
+#                         kernel; in and out, double-buffered, take 4x in VMEM
+
+
+def slot_pairs(D: int):
+    """The Q = D(D-1)/2 slot pairs s < t of a receiver, in lexicographic
+    order — the order of ``secure_mask_apply_pairs_keyed``'s pair keys —
+    as two (Q,) index arrays (lo, hi)."""
+    return np.triu_indices(D, 1)
 
 
 def _threefry2x32(k1, k2, x0, x1):
@@ -135,35 +149,50 @@ def _threefry2x32(k1, k2, x0, x1):
     return x0, x1
 
 
-def _kernel_nodes_keyed(bound_ref, x_ref, k1_ref, k2_ref, signs_ref, o_ref, *,
-                        block_n: int):
-    """One (message-rows, param-block) program: expand each pair key's
-    counter bits for this block's positions, map to uniform [-b, b), apply
-    signed.
+def _kernel_pairs_keyed(bound_ref, x_ref, k1_ref, k2_ref, clo_ref, chi_ref,
+                        o_ref, *, block_n: int, sub: int):
+    """One (receiver-rows, param-block) program over all D messages of its
+    receivers: expand each slot pair's key once per position, map to
+    uniform [-b, b), add it with c_lo to the lower slot's message and with
+    c_hi to the higher slot's.
 
     Positional replication of jax's partitionable threefry expansion
     (see ``kernels.ref.counter_bits_ref``): position p of a draw is
     ``y0 ^ y1`` of the cipher on counter (0, p), so a block needs only
-    its own positions.  Pair slots run as a static loop over 2-D
-    (ROWS, BN) tiles, each slot's key words a (ROWS, 1) column.
+    its own positions.  Pairs run in lexicographic order, so message s
+    receives its terms in ascending co-neighbor slot order, each key
+    word a (ROWS, 1) column; an inner loop walks the block in (ROWS, sub)
+    lane tiles.
     """
-    j = pl.program_id(1)
-    x = x_ref[...].astype(jnp.float32)            # (R, BN)
-    k1 = k1_ref[...]                              # (R, K) uint32
+    D = x_ref.shape[0]
+    pairs = list(zip(*slot_pairs(D)))
+    k1 = k1_ref[...]                              # (R, Q) uint32
     k2 = k2_ref[...]
-    signs = signs_ref[...].astype(jnp.float32)    # (R, K)
+    c_lo = clo_ref[...].astype(jnp.float32)       # (R, Q)
+    c_hi = chi_ref[...].astype(jnp.float32)
     bound = bound_ref[...]                        # (1, 1): a vector operand,
     #                                               no scalar load from VMEM
-    q = jax.lax.convert_element_type(
-        jax.lax.broadcasted_iota(jnp.int32, x.shape, 1) + j * block_n,
-        jnp.uint32)                               # global positions
-    zero = jnp.zeros_like(q)
-    tot = None
-    for s in range(k1.shape[1]):
-        y0, y1 = _threefry2x32(k1[:, s:s + 1], k2[:, s:s + 1], zero, q)
-        m = _uniform(y0 ^ y1, bound) * signs[:, s:s + 1]
-        tot = m if tot is None else tot + m
-    o_ref[...] = (x + tot).astype(o_ref.dtype)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (x_ref.shape[1], sub), 1)
+    base = pl.program_id(1) * block_n
+
+    def tile(c, carry):
+        off = pl.multiple_of(c * sub, sub)
+        q = jax.lax.convert_element_type(
+            lanes + (base + off), jnp.uint32)     # global positions
+        zero = jnp.zeros_like(q)
+        tot = [None] * D
+        for p, (s, t) in enumerate(pairs):
+            y0, y1 = _threefry2x32(k1[:, p:p + 1], k2[:, p:p + 1], zero, q)
+            m = _uniform(y0 ^ y1, bound)
+            for slot, coef in ((s, c_lo), (t, c_hi)):
+                term = m * coef[:, p:p + 1]
+                tot[slot] = term if tot[slot] is None else tot[slot] + term
+        for s in range(D):
+            x = x_ref[s, :, pl.ds(off, sub)].astype(jnp.float32)
+            o_ref[s, :, pl.ds(off, sub)] = (x + tot[s]).astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, block_n // sub, tile, 0)
 
 
 def _uniform(bits, bound):
@@ -173,35 +202,48 @@ def _uniform(bits, bound):
     return (u01.astype(jnp.float32) * (1.0 / (1 << 24)) * 2.0 - 1.0) * bound
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "block_n"))
-def secure_mask_apply_nodes_keyed(x, keys, signs, bound: float = 1.0, *,
-                                  interpret: bool = False,
-                                  block_n: int = BLOCK_N_KEYED):
-    """Fused mask apply with in-kernel bit generation.
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def secure_mask_apply_pairs_keyed(xs, keys, signs, bound: float = 1.0, *,
+                                  interpret: bool = False):
+    """Mask every message of a secure-aggregation round, each pair mask
+    expanded once.
 
-    x: (B, M) messages; keys: (B, K, 2) uint32 pair-PRF key words
-    (``jax.random.key_data`` of the folded-in pair keys); signs: (B, K) in
-    {-1, 0, +1} -> (B, M).  Equivalent to staging
-    ``jax.random.bits(key, (M,))`` per pair and calling
-    ``secure_mask_apply_nodes`` — without the (B, K, M) bit tensor.  Grid
-    (B/ROWS, M/BN); ragged edges are partial blocks (the op is
+    xs: (D, B, M) slot-major messages (slot s of receiver b is the message
+    its s-th neighbor sends it); keys: (B, Q, 2) uint32 pair-PRF key words
+    (``jax.random.key_data``) of the Q = D(D-1)/2 slot pairs in
+    ``slot_pairs`` order; signs: (B, D, D), signs[b, s, t] the coefficient
+    of pair {s, t}'s mask in message s (the diagonal is not read).  Returns
+    (D, B, M) with out[s] = xs[s] + sum over co-slots t (ascending) of
+    signs[:, s, t] * U(bits(key)), the bits being ``jax.random.bits(key,
+    (M,))``.  Equal, bit for bit, to ``secure_mask_apply_nodes`` fed each
+    message's staged bits with a zero-signed own slot
+    (``kernels.ref.pairs_to_slots``).  D = 1 has no pairs: xs comes back
+    unmasked.  Grid (B/ROWS, M/bn), bn sized from D so a message block
+    holds ``BLOCK_BYTES``; ragged edges are partial blocks (the op is
     elementwise per position, so out-of-range lanes are dropped).
     """
-    B, K, _ = keys.shape
-    M = x.shape[1]
-    bn, rows = min(block_n, M), min(ROWS, B)
-    row_spec = pl.BlockSpec((rows, K), lambda b, i: (b, 0))
+    D, B, M = xs.shape
+    Q = keys.shape[1]
+    if Q != D * (D - 1) // 2:
+        raise ValueError(f"{Q} pair keys for {D} slots; expected {D * (D - 1) // 2}")
+    if Q == 0:
+        return xs
+    lo, hi = slot_pairs(D)
+    bn = min(max(BLOCK_SUB, BLOCK_BYTES // (4 * ROWS * D) // BLOCK_SUB * BLOCK_SUB), M)
+    rows = min(ROWS, B)
+    sub = BLOCK_SUB if bn % BLOCK_SUB == 0 else bn
+    msg_spec = pl.BlockSpec((D, rows, bn), lambda b, i: (0, b, i))
+    row_spec = pl.BlockSpec((rows, Q), lambda b, i: (b, 0))
     return pl.pallas_call(
-        functools.partial(_kernel_nodes_keyed, block_n=bn),
+        functools.partial(_kernel_pairs_keyed, block_n=bn, sub=sub),
         grid=(pl.cdiv(B, rows), pl.cdiv(M, bn)),
         in_specs=[
             pl.BlockSpec((1, 1), lambda b, i: (0, 0)),
-            pl.BlockSpec((rows, bn), lambda b, i: (b, i)),
-            row_spec, row_spec, row_spec,
+            msg_spec, row_spec, row_spec, row_spec, row_spec,
         ],
-        out_specs=pl.BlockSpec((rows, bn), lambda b, i: (b, i)),
-        out_shape=jax.ShapeDtypeStruct((B, M), x.dtype),
+        out_specs=msg_spec,
+        out_shape=jax.ShapeDtypeStruct(xs.shape, xs.dtype),
         interpret=interpret,
         name="secure_mask_keyed",
-    )(jnp.asarray(bound, jnp.float32).reshape(1, 1), x, keys[:, :, 0],
-      keys[:, :, 1], signs)
+    )(jnp.asarray(bound, jnp.float32).reshape(1, 1), xs, keys[:, :, 0],
+      keys[:, :, 1], signs[:, lo, hi], signs[:, hi, lo])

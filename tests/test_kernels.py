@@ -4,7 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops, ref
+from repro.kernels import ops, ref, secure_mask
 
 
 class TestGossipMix:
@@ -169,34 +169,65 @@ class TestThreefryKernel:
         got = ref.counter_bits_ref(kd[0], kd[1], jnp.arange(P), P)
         np.testing.assert_array_equal(np.asarray(got), want)
 
-    def test_keyed_kernel_bit_identical_to_bits_kernel(self):
-        """secure_mask_apply_nodes_keyed(keys) == secure_mask_apply_nodes
-        (pre-expanded jax.random bits) — bit-for-bit, odd M."""
-        B, K, M = 3, 4, 333
-        x = jax.random.normal(jax.random.key(7), (B, M))
-        base = jax.random.key(9)
-        ids = jnp.arange(B * K).reshape(B, K)
-        keys = jax.vmap(jax.vmap(
-            lambda i: jax.random.key_data(jax.random.fold_in(base, i))))(ids)
-        bits = jax.vmap(jax.vmap(
-            lambda i: jax.random.bits(jax.random.fold_in(base, i), (M,), jnp.uint32)
-        ))(ids)
-        signs = jnp.asarray(
-            np.random.default_rng(0).choice([-1.0, 0.0, 1.0], (B, K)), jnp.float32
-        )
-        a = ops.secure_mask_apply_nodes(x, bits, signs, 0.9)
-        b = ops.secure_mask_apply_nodes_keyed(x, keys, signs, 0.9)
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    @staticmethod
+    def _pair_operands(D, B, padded):
+        """Keys of the D(D-1)/2 slot pairs and (B, D, D) coefficients,
+        signed as a receiver signs them (the own slot, which the kernel
+        does not read, set to 1); a padded last slot (validf 0) masks
+        nothing and takes no mask."""
+        lo, hi = secure_mask.slot_pairs(D)
+        keys = jax.random.bits(jax.random.key(D * B), (B, len(lo), 2), jnp.uint32)
+        sign = np.random.default_rng(D).choice([-1.0, 1.0], (B, len(lo)))
+        valid = np.ones((B, D))
+        if padded:
+            valid[:, -1] = 0.0
+        signs = np.ones((B, D, D))
+        signs[:, lo, hi] = sign * valid[:, hi]
+        signs[:, hi, lo] = -sign * valid[:, lo]
+        return keys, jnp.asarray(signs, jnp.float32)
 
-    @pytest.mark.parametrize("B,K,M", [(2, 3, 128), (5, 2, 70001)])
-    def test_keyed_kernel_matches_ref(self, B, K, M):
-        x = jax.random.normal(jax.random.key(M), (B, M))
-        keys = jax.random.bits(jax.random.key(1), (B, K, 2), jnp.uint32)
-        signs = jnp.where(jnp.arange(K)[None, :] % 2 == 0, 1.0, -1.0) * jnp.ones((B, 1))
-        got = ops.secure_mask_apply_nodes_keyed(x, keys, signs, 1.3)
-        want = ref.secure_mask_apply_nodes_keyed_ref(x, keys, signs, 1.3)
+    @pytest.mark.parametrize("D,B,M,padded", [
+        (2, 3, 333, False),
+        (5, 9, 333, True),
+        (5, 4, 7001, True),
+    ])
+    def test_keyed_kernel_bit_identical_to_bits_kernel(self, D, B, M, padded):
+        """secure_mask_apply_pairs_keyed(pair keys) == secure_mask_apply_nodes
+        fed each message's pre-expanded jax.random bits (D slots, own slot
+        zero-signed) — bit-for-bit, odd M; at D = 5, M = 7001 two message
+        blocks of six inner tiles, the second block ragged."""
+        xs = jax.random.normal(jax.random.key(7), (D, B, M))
+        keys, signs = self._pair_operands(D, B, padded)
+        got = secure_mask.secure_mask_apply_pairs_keyed(
+            xs, keys, signs, 0.9, interpret=True)
+        slot_keys, slot_signs = ref.pairs_to_slots(keys, signs)
+        expand = jax.vmap(jax.vmap(lambda kd: jax.random.bits(
+            jax.random.wrap_key_data(kd), (M,), jnp.uint32)))
+        for s in range(D):
+            want = ops.secure_mask_apply_nodes(xs[s], expand(slot_keys[s]),
+                                               slot_signs[s], 0.9)
+            np.testing.assert_array_equal(np.asarray(got[s]), np.asarray(want))
+
+    @pytest.mark.parametrize("D,B,M", [(2, 3, 128), (5, 2, 70001)])
+    def test_keyed_kernel_matches_ref(self, D, B, M):
+        xs = jax.random.normal(jax.random.key(M), (D, B, M))
+        keys, signs = self._pair_operands(D, B, padded=False)
+        got = ops.secure_mask_apply_pairs_keyed(xs, keys, signs, 1.3)
+        want = ref.secure_mask_apply_pairs_keyed_ref(xs, keys, signs, 1.3)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-5, atol=1e-5)
+
+    def test_keyed_kernel_single_slot_is_unmasked(self):
+        """D = 1: no co-neighbor pairs, so the message goes out as it is; a
+        pair count that does not match D is refused."""
+        xs = jax.random.normal(jax.random.key(4), (1, 3, 200))
+        got = ops.secure_mask_apply_pairs_keyed(
+            xs, jnp.zeros((3, 0, 2), jnp.uint32), jnp.zeros((3, 1, 1)))
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(xs))
+        with pytest.raises(ValueError):
+            ops.secure_mask_apply_pairs_keyed(
+                jnp.zeros((3, 3, 200)), jnp.zeros((3, 2, 2), jnp.uint32),
+                jnp.zeros((3, 3, 3)))
 
 
 class TestSSDChunk:

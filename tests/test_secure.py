@@ -58,6 +58,14 @@ class TestSecureAggregation:
         plain = 4.0 * 1000 * 4
         assert abs(nbytes / plain - 1.03) < 1e-6
 
+    def test_single_neighbour_round_equals_plain(self):
+        """Degree 1: a receiver has no co-neighbor pair, so its one message
+        goes unmasked and the round is the plain MH aggregate."""
+        g, X, W = _setup(n=12, degree=1, p=64)
+        X2, _, _ = SecureAggregation(g.adj).round(X, W, (), jax.random.key(6),
+                                                  degree=1.0, rnd=2)
+        np.testing.assert_allclose(np.asarray(X2), np.asarray(W @ X), rtol=5e-4, atol=5e-5)
+
     def test_mean_preserved(self):
         g, X, W = _setup(n=12, degree=5, p=64)
         s = SecureAggregation(g.adj)
@@ -173,6 +181,15 @@ class TestSeedRecovery:
         plain = SecureAggregation(g.adj)
         rec = SecureAggregation(g.adj, recovery=True)
         assert rec.stage_bytes_per_round(8, 128) == 2 * plain.stage_bytes_per_round(8, 128)
+
+    def test_prf_words_per_round(self):
+        """One cipher word per position of each receiver's C(D, 2)
+        co-neighbor pair masks; the recovery pass expands them again."""
+        g = Graph.regular_circulant(12, 5)
+        words = 12 * 10 * 1000
+        assert SecureAggregation(g.adj).prf_words_per_round(12, 1000) == words
+        rec = SecureAggregation(g.adj, recovery=True)
+        assert rec.prf_words_per_round(12, 1000) == 2 * words
 
     def test_full_participation_recovery_is_a_noop(self):
         """With everyone live the recovery pass subtracts nothing: same

@@ -18,6 +18,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels import gossip_mix, secure_mask, sparsify
 
 N, D, P = 256, 5, 579_594
+Q = D * (D - 1) // 2  # co-neighbor slot pairs of a receiver
 K_TOPK = int(0.01 * P)  # top-k sharing at budget 0.01
 
 
@@ -55,9 +56,9 @@ CASES = {
         [((1 + D, N, P), jnp.float32), ((N, 1 + D), jnp.float32)],
     ),
     "secure_mask_keyed": (
-        lambda x, k, s: secure_mask.secure_mask_apply_nodes_keyed(
+        lambda x, k, s: secure_mask.secure_mask_apply_pairs_keyed(
             x, k, s, 1.0, interpret=False),
-        [((N, P), jnp.float32), ((N, D, 2), jnp.uint32), ((N, D), jnp.float32)],
+        [((D, N, P), jnp.float32), ((N, Q, 2), jnp.uint32), ((N, D, D), jnp.float32)],
     ),
     "abs_survival_rows": (
         lambda x, e: sparsify.abs_histogram_rows(x, e, interpret=False),
